@@ -15,6 +15,9 @@ Env knobs:
                            subset, which lower-bounds the speedup because
                            the batched engine amortizes its single compile
                            over more points)
+  JAX_COMPILATION_CACHE_DIR  JAX's persistent compilation cache (default:
+                           the fixed ``.jax_cache`` in the checkout; see
+                           repro.compile_cache)
   MEMSIM_EXEC_CACHE_DIR    persistent executable cache (bench_stream manages
                            its own temp dir for its subprocess legs; setting
                            this globally additionally persists the other
@@ -29,20 +32,6 @@ import json
 import os
 import time
 from typing import Dict, List
-
-# The batched engine dispatches sweep lanes concurrently across host
-# devices; on a plain-CPU box XLA exposes one device unless told otherwise.
-# Must happen before jax initializes (all jax imports in this module are
-# deliberately function-local).
-if "XLA_FLAGS" not in os.environ:
-    try:
-        cpus = len(os.sched_getaffinity(0))  # Linux: honors cgroup limits
-    except AttributeError:
-        cpus = os.cpu_count() or 1
-    cpus = min(cpus, 8)
-    if cpus > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={cpus}")
 
 _ROWS: List[Dict] = []
 _ENGINE: Dict = {}
@@ -545,7 +534,19 @@ def bench_stream() -> None:
     import sys
     import tempfile
 
+    import jax
     import numpy as np
+    from jax._src import xla_bridge
+
+    # one process per chip: the legs are child interpreters that need the
+    # device, which a parent that already initialized an accelerator holds
+    if (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "cpu"):
+        raise RuntimeError(
+            f"bench_stream starts child processes that need the "
+            f"{jax.default_backend()} device, but this process already "
+            f"holds it; run the section alone in a fresh process: "
+            f"python benchmarks/run.py --only stream")
 
     from repro.core import engine as eng
     from repro.core.params import MemSimConfig
@@ -1413,6 +1414,20 @@ _SECTIONS = [
 ]
 
 
+def _cpu_devices() -> None:
+    """Give the CPU backend one device per core (up to 8), so lanes-mode
+    sweeps run concurrently across host devices. It must run before JAX
+    initializes a backend, and it only sizes the CPU backend: an
+    accelerator platform keeps its own devices."""
+    import jax
+
+    try:
+        cpus = len(os.sched_getaffinity(0))  # Linux: honors cgroup limits
+    except AttributeError:
+        cpus = os.cpu_count() or 1
+    jax.config.update("jax_num_cpu_devices", min(cpus, 8))
+
+
 def main(argv=None) -> None:
     names = [n for n, _, _ in _SECTIONS]
     parser = argparse.ArgumentParser(description=__doc__)
@@ -1436,6 +1451,10 @@ def main(argv=None) -> None:
     else:
         selected = set(names)
 
+    from repro.compile_cache import enable_compile_cache
+
+    _cpu_devices()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, bench, wrap in _SECTIONS:
         if name not in selected:

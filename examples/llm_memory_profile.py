@@ -12,6 +12,7 @@ refines the roofline memory term (EXPERIMENTS.md §Perf-beyond).
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import MemSimConfig
 from repro.perfmodel.analytic import cell_cost, param_counts, HBM_BW
@@ -19,6 +20,7 @@ from repro.perfmodel.effective_bw import decode_efficiency
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-72b")
     ap.add_argument("--shape", default="decode_32k")

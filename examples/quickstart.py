@@ -9,11 +9,13 @@ the latency breakdown, and the power report.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import MemSimConfig, simulate, simulate_ideal, stats
 from repro.core.power import PowerConfig, energy_report
 from repro.traces import conv2d
 
 def main() -> None:
+    enable_compile_cache()
     # 1. configuration: paper Table 1 timing parameters, queueSize=128
     cfg = MemSimConfig(queue_size=128)
     print(f"topology: {cfg.channels}ch x {cfg.ranks}rk x {cfg.bankgroups}bg "

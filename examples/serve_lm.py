@@ -16,12 +16,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import lm, registry
 from repro.models.layers import rmsnorm
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b")
     ap.add_argument("--batch", type=int, default=8)

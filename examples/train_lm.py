@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.store import CheckpointStore
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.launch.steps import make_train_step
@@ -25,6 +26,7 @@ from repro.optim import AdamWConfig, adamw_init, schedules
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

@@ -775,27 +775,63 @@ def _shard_pad(batch: int) -> int:
     return (-batch) % len(devices)
 
 
-def _maybe_shard(tree, batch: int) -> Tuple[object, bool]:
+def _maybe_shard(tree, batch: int):
     """Shard the leading batch axis across visible devices.
 
-    Returns ``(tree, sharded)``. Callers are expected to have padded
-    ``batch`` to a device multiple via :func:`_shard_pad`; a non-multiple
-    batch (or a single device) is left unsharded."""
+    Returns ``(tree, mesh)``: the mesh (one ``"data"`` axis over every
+    device) the batch now lives on, or None on a single device, which
+    leaves it unsharded. Callers pad ``batch`` to a device multiple via
+    :func:`_shard_pad`. There is no fallback: a batch that cannot be
+    placed across the devices raises instead of silently running on one."""
     devices = jax.devices()
-    if len(devices) <= 1 or batch % len(devices) != 0:
-        return tree, False
-    try:
-        from jax.sharding import Mesh
+    if len(devices) <= 1:
+        return tree, None
+    if batch % len(devices) != 0:
+        raise ValueError(f"batch of {batch} lanes does not divide over "
+                         f"{len(devices)} devices (pad with _shard_pad)")
+    from jax.sharding import Mesh
 
-        from repro.distributed import shard as shard_lib
+    from repro.distributed import shard as shard_lib
 
-        mesh = Mesh(np.asarray(devices), ("data",))
-        with shard_lib.use_mesh(mesh):
-            sharding = shard_lib.named(mesh, "data")
-        return jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, sharding), tree), True
-    except Exception:  # pragma: no cover - single-device fallback
-        return tree, False
+    mesh = Mesh(np.asarray(devices), ("data",))
+    sharding = shard_lib.named(mesh, "data")
+    return jax.tree_util.tree_map(
+        lambda x: jax.device_put(x, sharding), tree), mesh
+
+
+# A sharded batch is split over the devices explicitly: XLA cannot
+# partition a Pallas kernel, so each device runs the batched engine on its
+# own block of lanes. In the event-horizon engine each device then keeps
+# its own shared clock, which leaves every lane bit-exact (executing an
+# inert cycle is bit-identical to skipping it) and needs no collective.
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _run_skip_batch_split_jit(mesh, topo, traces, num_cycles, scheds,
+                              queue_limits, resp_limits):
+    from jax.sharding import PartitionSpec as P
+
+    def per_device(tr, nc, sc, ql, rl):
+        states, steps = _run_skip_batch_core(topo, tr, nc, sc, ql, rl)
+        return states, steps[None]
+
+    lanes = P("data")
+    return jax.shard_map(per_device, mesh=mesh,
+                         in_specs=(lanes, P(), lanes, lanes, lanes),
+                         out_specs=lanes, check_vma=False)(
+        traces, num_cycles, scheds, queue_limits, resp_limits)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 3))
+def _run_scan_batch_split_jit(mesh, topo, traces, num_cycles, scheds,
+                              queue_limits, resp_limits):
+    from jax.sharding import PartitionSpec as P
+
+    lanes = P("data")
+    return jax.shard_map(
+        lambda tr, sc, ql, rl: _run_scan_batch_jit(topo, tr, num_cycles, sc,
+                                                   ql, rl),
+        mesh=mesh, in_specs=lanes, out_specs=lanes, check_vma=False)(
+        traces, scheds, queue_limits, resp_limits)
 
 
 # --------------------------------------------------------------------------
@@ -1226,29 +1262,41 @@ def simulate_batch(cfg: MemSimConfig,
         sched_stack = ParamSchedule.stack(scheds + [scheds[0]] * pad_lanes)
         ql = jnp.asarray(qs + [qs[0]] * pad_lanes, jnp.int32)
         rl = jnp.asarray(rs + [rs[0]] * pad_lanes, jnp.int32)
-        sharded = False
+        mesh = None
         if shard:
-            (stacked, sched_stack, ql, rl), sharded = _maybe_shard(
+            (stacked, sched_stack, ql, rl), mesh = _maybe_shard(
                 (stacked, sched_stack, ql, rl), lanes + pad_lanes)
         if timings is not None:
             timings["pad_lanes"] = timings.get("pad_lanes", 0) + pad_lanes
-            timings["sharded"] = sharded
+            timings["sharded"] = mesh is not None
             timings["devices"] = len(jax.devices())
 
         if cycle_skip:
-            nc = jnp.int32(num_cycles)
-            finals, steps = _timed(_run_skip_batch_jit,
-                                   (topo, stacked, nc, sched_stack, ql, rl),
-                                   (stacked, nc, sched_stack, ql, rl),
-                                   (topo,), timings)
+            dyn = (stacked, jnp.int32(num_cycles), sched_stack, ql, rl)
+            if mesh is None:
+                finals, steps = _timed(_run_skip_batch_jit, (topo,) + dyn,
+                                       dyn, (topo,), timings)
+            else:
+                finals, steps = _timed(_run_skip_batch_split_jit,
+                                       (mesh, topo) + dyn, dyn, (mesh, topo),
+                                       timings)
         else:
-            finals, steps = _timed(_run_scan_batch_jit,
-                                   (topo, stacked, num_cycles, sched_stack,
-                                    ql, rl),
-                                   (stacked, sched_stack, ql, rl),
-                                   (topo, num_cycles), timings)
+            dyn = (stacked, sched_stack, ql, rl)
+            statics = (topo, num_cycles)
+            if mesh is None:
+                finals, steps = _timed(_run_scan_batch_jit,
+                                       (topo, stacked, num_cycles) + dyn[1:],
+                                       dyn, statics, timings)
+            else:
+                finals, steps = _timed(_run_scan_batch_split_jit,
+                                       (mesh, topo, stacked, num_cycles)
+                                       + dyn[1:], dyn, (mesh,) + statics,
+                                       timings)
         if timings is not None:
             timings["steps"] = int(np.max(np.asarray(steps)))
+            # the devices the lane results actually live on
+            timings["devices_used"] = sorted(
+                d.id for d in finals.t_complete.sharding.device_set)
         host = jax.device_get(finals)
 
         def lane_field(i, name):

@@ -10,9 +10,9 @@ they are the literal shared helpers of ``cycle_step``, so the fused path
 cannot drift from the reference semantics there by construction.
 
 ``fused_cycle_step_batch`` is the vmap-mode twin: the per-lane XLA glue
-is vmapped (it vectorizes cleanly), but the kernel operands are folded
-lane-major into the bank axis and dispatched as ONE lane-batched
-``pallas_call`` for the whole batch — ``jax.vmap`` over a ``pallas_call``
+is vmapped (it vectorizes cleanly), but the kernel operands are laid
+out lanes-on-sublanes (:func:`_kernel_layout`) and dispatched as ONE
+lane-batched ``pallas_call`` for the whole batch — ``jax.vmap`` over a ``pallas_call``
 would instead serialize the kernel per lane through the interpret grid.
 
 Both return ``(new_state, delta)`` where ``delta`` is the exact
@@ -44,11 +44,8 @@ from repro.core.simulator import (
     _memory_phase,
     _promote_frfcfs,
 )
-from repro.kernels.bank_fsm.fused import (
-    NUM_SCAL_OUT,
-    fused_interpret,
-    fused_step_pallas,
-)
+from repro.kernels.bank_fsm.fused import NUM_SCAL_OUT, fused_step_pallas
+from repro.kernels.bank_fsm.ops import default_interpret
 from repro.kernels.bank_fsm.ref import pack_state, unpack_state
 
 # plain int, not a jnp constant (see ops.py: no trace-context leakage)
@@ -58,7 +55,8 @@ _INF = 0x3FFFFFFF
 def _pre(topo: Topology, sched, trace: Trace, state: SimState, cycle: Array,
          horizon):
     """Per-lane front-end glue + kernel operand packing (single-lane
-    shapes; the batch path vmaps this and folds the leading lane axis)."""
+    shapes; the batch path vmaps this and :func:`_kernel_layout` moves the
+    leading lane axis to where the kernel ABI wants it)."""
     seg = sched.segment_at(cycle)
     # the kernel re-resolves every timing/policy param in-kernel; the only
     # glue consumers are the FR-FCFS promote flag and (tiered topologies)
@@ -185,6 +183,28 @@ def _post(topo: Topology, n: int, state: SimState, cycle: Array, ctx,
     return new_state, delta
 
 
+def _kernel_layout(ops):
+    """Lane-stacked per-lane operands ([L, ...] leading axis, as ``_pre``
+    under vmap produces them) -> the kernel ABI of
+    :mod:`repro.kernels.bank_fsm.fused`: bank rows [23, L, B], resp_buf
+    [F, L, Qr], rp_mat [L, T*S*NP], bounds [L, S], scal [L, 8+C]."""
+    bank_rows, resp_buf, rp_mat, bounds, scal = ops
+    lanes = bank_rows.shape[0]
+    return (jnp.moveaxis(bank_rows, 0, 1),
+            jnp.transpose(resp_buf, (2, 0, 1)),
+            rp_mat.reshape(lanes, -1),
+            bounds.reshape(lanes, -1),
+            scal.reshape(lanes, -1))
+
+
+def _lane_outputs(outs):
+    """Kernel outputs -> lane-stacked per-lane shapes for ``_post``:
+    [L, 22, B], [L, Qr, F] and the [L, 9+2C] scalar rows."""
+    bank2, resp_buf2, scal2 = outs
+    return (jnp.moveaxis(bank2, 1, 0), jnp.transpose(resp_buf2, (1, 2, 0)),
+            scal2)
+
+
 def fused_cycle_step(topo: Topology, sched, trace: Trace, state: SimState,
                      cycle: Array, horizon) -> Tuple[SimState, Array]:
     """One synchronous clock edge + the event bound at ``cycle + 1``.
@@ -197,49 +217,27 @@ def fused_cycle_step(topo: Topology, sched, trace: Trace, state: SimState,
     sched = as_schedule(sched)
     cycle = jnp.asarray(cycle, jnp.int32)
     ops, ctx = _pre(topo, sched, trace, state, cycle, horizon)
-
-    interpret = fused_interpret(topo, sched.num_segments)
-    bank2, resp_buf2, scal2 = fused_step_pallas(topo, *ops,
-                                                interpret=interpret)
-    return _post(topo, trace.num_requests, state, cycle, ctx,
-                 (bank2, resp_buf2, scal2[0]))
+    outs = fused_step_pallas(
+        topo, *_kernel_layout(tuple(x[None] for x in ops)),
+        interpret=default_interpret())
+    outs = tuple(x[0] for x in _lane_outputs(outs))
+    return _post(topo, trace.num_requests, state, cycle, ctx, outs)
 
 
 def fused_cycle_step_batch(topo: Topology, scheds, traces, states,
                            cycle: Array, horizon) -> Tuple[SimState, Array]:
     """Lane-batched twin of :func:`fused_cycle_step` for the vmap-mode
-    skip engine: per-lane glue under ``jax.vmap``, kernel operands folded
-    lane-major into the bank axis, ONE lane-batched dispatch per executed
-    cycle for the whole batch. Returns stacked states and per-lane deltas
-    (the engine skips by their min, same as the unfused vmap path)."""
+    skip engine: per-lane glue under ``jax.vmap``, kernel operands laid
+    out lanes-on-sublanes, ONE lane-batched dispatch per executed cycle
+    for the whole batch. Returns stacked states and per-lane deltas (the
+    engine skips by their min, same as the unfused vmap path)."""
     cycle = jnp.asarray(cycle, jnp.int32)
     ops, ctx = jax.vmap(
         lambda tr, sc, st: _pre(topo, sc, tr, st, cycle, horizon)
     )(traces, scheds, states)
-
-    bank_rows, resp_buf, rp_mat, bounds, scal = ops
-    lanes = bank_rows.shape[0]
-    num_segments = bounds.shape[1]       # bounds [L, S, 1]; rp [L, T*S, NP]
-    folded = (
-        # [L, 23, B] -> [23, L*B] lane-major
-        jnp.moveaxis(bank_rows, 0, 1).reshape(bank_rows.shape[1], -1),
-        resp_buf.reshape(-1, resp_buf.shape[-1]),
-        rp_mat.reshape(-1, rp_mat.shape[-1]),
-        bounds.reshape(-1, 1),
-        scal.reshape(lanes, -1),
-    )
-
-    interpret = fused_interpret(topo, num_segments, lanes)
-    bank2, resp_buf2, scal2 = fused_step_pallas(topo, *folded,
-                                                interpret=interpret,
-                                                lanes=lanes)
-
-    outs = (
-        # [22, L*B] -> [L, 22, B]
-        jnp.moveaxis(bank2.reshape(bank2.shape[0], lanes, -1), 0, 1),
-        resp_buf2.reshape(lanes, -1, resp_buf2.shape[-1]), scal2)
-
+    outs = fused_step_pallas(topo, *_kernel_layout(ops),
+                             interpret=default_interpret())
     n = traces.t.shape[-1]               # per-lane request count (uniform)
     return jax.vmap(
         lambda st, ctx_l, out_l: _post(topo, n, st, cycle, ctx_l, out_l)
-    )(states, ctx, outs)
+    )(states, ctx, _lane_outputs(outs))

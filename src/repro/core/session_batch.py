@@ -278,6 +278,7 @@ class SessionBatch:
         steps = np.asarray(steps)
         per_steps = (steps.astype(np.int64).tolist() if steps.ndim
                      else [int(steps)] * self.lanes)
+        self.timings["steps"] = self.timings.get("steps", 0) + max(per_steps)
         return [
             _build_report(t0, t1, self._n_filled[i], per_steps[i],
                           t_complete[i], req_q[i], resp_q[i], admitted[i],

@@ -28,38 +28,41 @@ from 2 to 1 with the remaining glue absorbed into the same jitted body.
 
 The kernel is natively LANE-BATCHED: ``lanes`` independent sweeps (each
 its own trace position, queues, schedule and arbiter pointers, all on the
-engine's shared batch clock) fold into the bank axis, so the vmapped
-batch runner pays ONE dispatch per executed cycle for the whole batch —
-not one per lane, which is what ``jax.vmap`` over a ``pallas_call`` would
-serialize into via the grid. All cross-bank reductions (both arbiters,
-the inert gate, the event bound) are segmented reshape reductions over
-``[lanes * channels, banks_per_channel]`` / ``[lanes, B]`` matrices, so
-the op count is independent of both the lane count and the channel count.
+engine's shared batch clock) share one dispatch per executed cycle — not
+one per lane, which is what ``jax.vmap`` over a ``pallas_call`` would
+serialize into via the grid.
+
+Layout. Every per-bank quantity is an [L, B] block: lanes on sublanes,
+banks on the lane axis. Per-lane scalars are [L, 1] columns. Nothing is
+ever reshaped across the lane axis (Mosaic refuses such shape casts), so
+every cross-bank reduction — both arbiters, the inert gate, the event
+bound — is a lane-axis reduction of an [L, B] block; the per-channel
+command arbiter masks each channel's static bank segment. The op count
+is independent of the lane count.
 
 ABI (all int32; L = lanes, B = banks per lane, Qr = resp capacity,
-F = 4 request fields, S = schedule segments, C = channels; lane-major
-bank axis, i.e. position = lane * B + bank). The per-bank rows travel as
-ONE [ROWS, L*B] operand per direction — interpret mode copies every
-operand into its block each dispatch, so operand count and size are paid
-per executed cycle (this is also why the queue head PEEK — a gather the
-split path already does in glue — feeds the kernel as 4 pop rows instead
-of shipping the whole [L*B, Q*F] queue buffer through the ABI; the pop
-BOOKKEEPING stays in-kernel):
+F = 4 request fields, S = schedule segments, T = schedule tiers,
+NP = NUM_RUNTIME_PARAMS, C = channels):
 
-  inputs   bank rows [23,L*B]: state 0-9 | qmeta 10-11 (head,count) |
+  inputs   bank rows [23, L, B]: state 0-9 | qmeta 10-11 (head,count) |
            timing 12-18 (last_act, act_win0..3, last_rd, last_wr gathered
            per-bank) | pop 19-22 (head items; garbage where empty) —
-           plus resp_buf [L*Qr,F] | rp_mat [L*T*S,NP] (T = topo.tiers,
-           tier-major within each lane's block) | bounds [L*S,1] |
-           scal [L, 8+C] = (cycle, arrival_rel, horizon, req_count,
-           resp_head, resp_count, resp_limit, resp_rr, cmd_rr[C]) per
-           lane (cycle/horizon are the shared clock)
-  outputs  bank rows [22,L*B]: new_state 0-9 | flags 10-12 | qmeta2
+           plus resp_buf [F, L, Qr] (field-major) | rp_mat [L, T*S*NP]
+           (each lane's tier-major ``ParamSchedule.pack`` matrix,
+           flattened) | bounds [L, S] | scal [L, 8+C] = (cycle,
+           arrival_rel, horizon, req_count, resp_head, resp_count,
+           resp_limit, resp_rr, cmd_rr[C]) per lane (cycle/horizon are
+           the shared clock)
+  outputs  bank rows [22, L, B]: new_state 0-9 | flags 10-12 | qmeta2
            13-14 | timing2 15-21 (rank-uniform; glue reduces back to [R])
-           — plus resp_buf2 [L*Qr,F] | scal2 [L, 9+2C] = (delta,
+           — plus resp_buf2 [F, L, Qr] | scal2 [L, 9+2C] = (delta,
            resp_rr2, resp_head2, resp_count2, ack_valid, fitem_addr,
            fitem_write, fitem_data, fitem_id, cmd_rr2[C], issued_cmd[C])
            per lane
+
+The queue head PEEK (a gather the split path already does in glue) feeds
+the kernel as 4 pop rows instead of shipping the whole queue buffer
+through the ABI; the pop BOOKKEEPING stays in-kernel.
 
 Bit-exactness against the unfused path is a structural property wherever
 possible (the FSM edge and local event bound are the *same* functions the
@@ -71,15 +74,12 @@ windows, queue ops, gate logic).
 from __future__ import annotations
 
 import functools
-import os
-import warnings
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.bank_fsm import EVENT_INF
 from repro.core.params import (
     CMD_ACT,
     CMD_NOP,
@@ -89,6 +89,7 @@ from repro.core.params import (
     CMD_SREF_ENTER,
     CMD_SREF_EXIT,
     CMD_WR,
+    NUM_RUNTIME_PARAMS,
     RP_INDEX,
     S_ACT_ISSUE,
     S_ACT_WAIT,
@@ -123,11 +124,6 @@ NUM_BANK_ROWS_OUT = 22   # state 10 + flags 3 + qmeta 2 + timing 7
 NUM_SCAL_IN = 8          # + channels
 NUM_SCAL_OUT = 9         # + 2 * channels
 
-# one-shot probe cache for the non-interpret path, keyed by
-# (topology, segment count, lanes): can Mosaic/Triton compile *this*
-# fused kernel at *this* batch width?
-_FUSED_NONINTERPRET_OK: Dict[tuple, bool] = {}
-
 
 def _compute_cmds(st, cur_write):
     """Lanewise :func:`repro.core.bank_fsm.compute_bids` (cmds only; a lane
@@ -157,97 +153,95 @@ def _legal_at(rp, cmd, la, aw0, aw1, aw2, aw3, lr, lw):
     return at.astype(jnp.int32)
 
 
-def _resolve_rp_lanes(rp_ref, bnd_ref, cycle, lanes, width, tiers: int = 1,
-                      tier_split: int = 0):
-    """Per-lane in-kernel ParamSchedule resolution: select each lane's
-    per-tier [NP] rows of the segment governing ``cycle`` from the stacked
-    [L*T*S, NP] matrix (lane-major, tier-major within a lane — each lane's
-    block is its own tier-major ``ParamSchedule.pack``), then serve
-    ``rp(name)`` as a [1, L*width] lane-broadcast row (what the shared
-    combinational networks consume); tiered topologies select per bank at
-    the static ``tier_split`` within each lane's bank block.
+def _resolve_rp_lanes(rp_ref, bnd_ref, cycle, lanes, width,
+                      tier_split: int):
+    """Per-lane in-kernel ParamSchedule resolution: serve ``rp(name)`` as
+    an [L, width] block (lanes on sublanes, banks on lanes — the layout of
+    every bank row the shared combinational networks consume).
 
-    The active segment per lane is the last one whose start boundary is
-    <= cycle (boundaries sorted; SCHEDULE_INF padding rows never
-    activate), found branchlessly per lane: count satisfied boundaries,
-    one-hot the row, reduce. S == 1 (the constant degenerate schedule)
-    reads the lane rows directly — the kernel specializes on the static
-    block shape, so constant-params programs pay nothing. Accessed rows
-    are memoized so each timing parameter broadcasts once per resolve."""
-    s = rp_ref.shape[0] // (lanes * tiers)
-    if s == 1:
-        rows = rp_ref[...].reshape(lanes, tiers, -1)            # [L, T, NP]
-    else:
-        bnd = bnd_ref[...].reshape(lanes, s)
-        segs = jnp.sum((bnd <= cycle).astype(jnp.int32), axis=1) - 1
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (lanes, s), 1)
-                  == segs[:, None]).astype(jnp.int32)
-        rows = jnp.sum(rp_ref[...].reshape(lanes, tiers, s, -1)
-                       * onehot[:, None, :, None], axis=2)      # [L, T, NP]
-
+    ``rp_ref`` is [L, T*S*NP]: each lane's own tier-major
+    ``ParamSchedule.pack`` values matrix flattened on the lane axis, so
+    parameter ``j`` of tier ``t`` in segment ``s`` is column
+    ``(t*S + s)*NP + j`` — a static column slice. The active segment per
+    lane is the last one whose start boundary is <= cycle (boundaries
+    sorted; SCHEDULE_INF padding never activates), found branchlessly:
+    count satisfied boundaries, then a select chain over the S static
+    columns (exactly the one-hot row sum, without a reshape). S == 1 (the
+    constant degenerate schedule) reads the column directly. A tier-stacked
+    schedule (T = 2) selects per bank at the static ``tier_split``; a
+    single-tier one serves every bank, as the jnp reference does. Accessed
+    parameters are memoized so each broadcasts once per resolve."""
+    s = bnd_ref.shape[1]
+    tiers = rp_ref.shape[1] // (s * NUM_RUNTIME_PARAMS)
+    seg = (jnp.sum((bnd_ref[...] <= cycle).astype(jnp.int32), axis=1,
+                   keepdims=True) - 1) if s > 1 else None      # [L, 1]
     cache: Dict[str, jax.Array] = {}
     bi = (jax.lax.broadcasted_iota(jnp.int32, (lanes, width), 1)
           if tiers > 1 else None)
 
+    def col(t, j):
+        if s == 1:
+            c = t * NUM_RUNTIME_PARAMS + j
+            return rp_ref[:, c:c + 1]
+        val = jnp.zeros((lanes, 1), jnp.int32)
+        for k in range(s):
+            c = (t * s + k) * NUM_RUNTIME_PARAMS + j
+            val = jnp.where(seg == k, rp_ref[:, c:c + 1], val)
+        return val
+
     def rp(name):
         if name not in cache:
-            col = rows[:, :, RP_INDEX[name]]                    # [L, T]
-            val = jnp.broadcast_to(col[:, 0:1], (lanes, width))
+            j = RP_INDEX[name]
+            val = jnp.broadcast_to(col(0, j), (lanes, width))
             for t in range(1, tiers):
                 # two tiers max (Topology.validate): one static threshold
-                val = jnp.where(bi >= tier_split, col[:, t:t + 1], val)
-            cache[name] = val.reshape(1, lanes * width)
+                val = jnp.where(bi >= tier_split, col(t, j), val)
+            cache[name] = val
         return cache[name]
 
     return rp
 
 
-def _fused_kernel(topo: Topology, lanes: int, bank_ref, resp_ref, rp_ref,
-                  bnd_ref, scal_ref, bank_out_ref, resp_out_ref,
-                  scal_out_ref):
+def _fused_kernel(topo: Topology, bank_ref, resp_ref, rp_ref, bnd_ref,
+                  scal_ref, bank_out_ref, resp_out_ref, scal_out_ref):
+    lanes = bank_ref.shape[1]
     b = topo.num_banks              # banks per lane
-    total = lanes * b
-    nf = resp_ref.shape[1]          # request fields (4)
-    qr = resp_ref.shape[0] // lanes  # resp queue capacity per lane
+    shape = (lanes, b)
+    nf = resp_ref.shape[0]          # request fields (4)
+    qr = resp_ref.shape[2]          # resp queue capacity per lane
     q_cap = topo.queue_size         # bank queue capacity
     per = topo.banks_per_channel
     channels = topo.channels
-    seg_rows = lanes * channels     # arbiter matrix: one row per lane-channel
 
-    # ---- per-lane scalars --------------------------------------------------
-    scal = scal_ref[...]
-    cycle = scal[0, 0]              # shared batch clock (same in every lane)
-    horizon = scal[0, 2]
-    arrival_rel = scal[:, 1]        # [L]
-    req_count = scal[:, 3]
-    resp_head = scal[:, 4]
-    resp_count = scal[:, 5]
-    resp_limit = scal[:, 6]
-    resp_rr = scal[:, 7]
-    cmd_rr = scal[:, NUM_SCAL_IN:NUM_SCAL_IN + channels]        # [L, C]
+    # ---- per-lane scalars: [L, 1] columns ----------------------------------
+    def scol(k):
+        return scal_ref[:, k:k + 1]
+
+    cycle = scol(0)                 # shared batch clock (same in every lane)
+    horizon = scol(2)
+    arrival_rel = scol(1)
+    req_count = scol(3)
+    resp_head = scol(4)
+    resp_count = scol(5)
+    resp_limit = scol(6)
+    resp_rr = scol(7)
+    cmd_rr = [scol(NUM_SCAL_IN + c) for c in range(channels)]
     nxt = cycle + 1
 
-    tiers = topo.tiers
-    split = topo.tier_split_bank if tiers > 1 else 0
-    rp = _resolve_rp_lanes(rp_ref, bnd_ref, cycle, lanes, b, tiers, split)
-    rp2 = _resolve_rp_lanes(rp_ref, bnd_ref, nxt, lanes, b, tiers, split)
+    split = topo.tier_split_bank
+    rp = _resolve_rp_lanes(rp_ref, bnd_ref, cycle, lanes, b, split)
+    rp2 = _resolve_rp_lanes(rp_ref, bnd_ref, nxt, lanes, b, split)
 
-    # ---- loads (one [23, L*B] operand; row map in the module docstring) ----
-    rows = tuple(bank_ref[i:i + 1, :] for i in range(10))
+    # ---- loads (one [23, L, B] operand; row map in the module docstring) ---
+    rows = tuple(bank_ref[i] for i in range(10))
     st = rows[0]
     cur_addr, cur_write, cur_data, cur_id = rows[4], rows[5], rows[6], rows[7]
-    qhead = bank_ref[10:11, :]
-    qcount = bank_ref[11:12, :]
-    la = bank_ref[12:13, :]
-    aw0 = bank_ref[13:14, :]
-    aw1 = bank_ref[14:15, :]
-    aw2 = bank_ref[15:16, :]
-    aw3 = bank_ref[16:17, :]
-    lr = bank_ref[17:18, :]
-    lw = bank_ref[18:19, :]
+    qhead = bank_ref[10]
+    qcount = bank_ref[11]
+    la, aw0, aw1, aw2, aw3, lr, lw = (bank_ref[12 + i] for i in range(7))
     # head items peeked by glue (garbage where the queue is empty, exactly
     # like the unfused peek — the FSM masks on queue_nonempty)
-    pop_rows = tuple(bank_ref[19 + f:20 + f, :] for f in range(nf))
+    pop_rows = tuple(bank_ref[19 + f] for f in range(nf))
     queue_nonempty = qcount > 0
 
     # ---- phase 3: bids, legality, per-channel RR grant, record_issue -------
@@ -256,84 +250,90 @@ def _fused_kernel(topo: Topology, lanes: int, bank_ref, resp_ref, rp_ref,
     legal = _legal_at(rp, cmds, la, aw0, aw1, aw2, aw3, lr, lw)
     eligible = bids & (cycle >= legal)
 
-    # segmented arbitration: [1, L*B] -> [L*C, per] puts each lane-channel
-    # in its own row, so every grant/min/rotation is ONE reduction over
-    # axis 1 regardless of lane or channel count (channels are disjoint,
-    # so the old static per-channel unroll order was irrelevant anyway)
-    elig_m = eligible.reshape(seg_rows, per)
-    wi = jax.lax.broadcasted_iota(jnp.int32, (seg_rows, per), 1)
-    ptr = cmd_rr.reshape(seg_rows, 1)
+    # per-channel arbitration on the [L, B] block: banks stay on the lane
+    # axis, each channel is a static lane segment. The per-channel
+    # reductions are masked lane reductions ([L, 1] results) broadcast
+    # back over the channel's banks, so nothing is reshaped across the
+    # lane axis (Mosaic cannot) and the op count is independent of the
+    # lane count.
+    bank = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    chan = bank // per
+    wi = bank % per                 # bank index within its channel
+    in_chan = [chan == c for c in range(channels)]
+
+    def per_channel(vals):
+        """[L, B] block holding each bank's channel's [L, 1] value."""
+        out = jnp.broadcast_to(vals[0], shape)
+        for c in range(1, channels):
+            out = jnp.where(in_chan[c], vals[c], out)
+        return out
+
+    def chan_reduce(fn, x, fill):
+        if channels == 1:
+            return [fn(x, axis=1, keepdims=True)]
+        return [fn(jnp.where(in_chan[c], x, fill), axis=1, keepdims=True)
+                for c in range(channels)]
+
+    ptr = per_channel(cmd_rr)
     rot = (wi - ptr) % per
-    key = jnp.where(elig_m, rot, per)
-    m = jnp.min(key, axis=1, keepdims=True)                     # [L*C, 1]
-    any_g = m < per
-    g_m = elig_m & (rot == m)
-    grant = g_m.reshape(1, total)
-    cmd_rr2 = jnp.where(any_g, (ptr + m + 1) % per, ptr).reshape(
-        lanes, channels)
-    g_i = g_m.astype(jnp.int32)
-    cmd_w = jnp.sum(g_i * cmds.reshape(seg_rows, per), axis=1,
-                    keepdims=True)              # CMD_NOP when no grant
-    issued = cmd_w.reshape(lanes, channels)
-    # record_issue, vectorized: every lane of the winner's rank holds the
+    key = jnp.where(eligible, rot, per)
+    m_c = chan_reduce(jnp.min, key, per)                        # C x [L, 1]
+    grant = eligible & (rot == per_channel(m_c))
+    g_i = grant.astype(jnp.int32)
+    # CMD_NOP (0) where the channel granted nothing
+    cmd_w_c = chan_reduce(jnp.sum, g_i * cmds, 0)
+    cmd_rr2 = [jnp.where(m_c[c] < per, (cmd_rr[c] + m_c[c] + 1) % per,
+                         cmd_rr[c]) for c in range(channels)]
+    # record_issue, vectorized: every bank of the winner's rank holds the
     # same register value, so a masked elementwise update is the scalar
-    # .at[rank] update broadcast per-bank (rank blocks align with channel
-    # blocks: ranks are channel-disjoint)
+    # .at[rank] update broadcast per-bank (ranks are channel-disjoint)
     rank_in = wi // topo.banks_per_rank
-    rank_w = jnp.sum(g_i * rank_in, axis=1, keepdims=True)
+    rank_w = per_channel(chan_reduce(jnp.sum, g_i * rank_in, 0))
+    cmd_w = per_channel(cmd_w_c)
+    any_g = per_channel(m_c) < per
     upd = rank_in == rank_w
     is_act = any_g & (cmd_w == CMD_ACT)
     is_rd = any_g & (cmd_w == CMD_RD)
     is_wr = any_g & (cmd_w == CMD_WR)
-    la_m = la.reshape(seg_rows, per)
-    aw0_m = aw0.reshape(seg_rows, per)
-    aw1_m = aw1.reshape(seg_rows, per)
-    aw2_m = aw2.reshape(seg_rows, per)
-    aw3_m = aw3.reshape(seg_rows, per)
-    la2 = jnp.where(is_act & upd, cycle, la_m)
+    la2 = jnp.where(is_act & upd, cycle, la)
     # tFAW window: replace the first-minimum slot (jnp.argmin ties to the
     # first occurrence; this select chain reproduces that exactly)
-    awm = jnp.minimum(jnp.minimum(aw0_m, aw1_m), jnp.minimum(aw2_m, aw3_m))
-    s0 = aw0_m == awm
-    s1 = (aw1_m == awm) & ~s0
-    s2 = (aw2_m == awm) & ~s0 & ~s1
+    awm = jnp.minimum(jnp.minimum(aw0, aw1), jnp.minimum(aw2, aw3))
+    s0 = aw0 == awm
+    s1 = (aw1 == awm) & ~s0
+    s2 = (aw2 == awm) & ~s0 & ~s1
     s3 = ~s0 & ~s1 & ~s2
     hit_act = is_act & upd
-    aw0_2 = jnp.where(hit_act & s0, cycle, aw0_m).reshape(1, total)
-    aw1_2 = jnp.where(hit_act & s1, cycle, aw1_m).reshape(1, total)
-    aw2_2 = jnp.where(hit_act & s2, cycle, aw2_m).reshape(1, total)
-    aw3_2 = jnp.where(hit_act & s3, cycle, aw3_m).reshape(1, total)
-    la2 = la2.reshape(1, total)
-    lr2 = jnp.where(is_rd & upd, cycle,
-                    lr.reshape(seg_rows, per)).reshape(1, total)
-    lw2 = jnp.where(is_wr & upd, cycle,
-                    lw.reshape(seg_rows, per)).reshape(1, total)
+    aw0_2 = jnp.where(hit_act & s0, cycle, aw0)
+    aw1_2 = jnp.where(hit_act & s1, cycle, aw1)
+    aw2_2 = jnp.where(hit_act & s2, cycle, aw2)
+    aw3_2 = jnp.where(hit_act & s3, cycle, aw3)
+    lr2 = jnp.where(is_rd & upd, cycle, lr)
+    lw2 = jnp.where(is_wr & upd, cycle, lw)
 
     # ---- phase 4: response arbitration + respQueue push --------------------
-    resp_full = resp_count >= resp_limit                        # [L]
-    bids_r = ((st == S_RESP_PEND).reshape(lanes, b)
-              & ~resp_full[:, None])
-    bi = jax.lax.broadcasted_iota(jnp.int32, (lanes, b), 1)
-    rot_r = (bi - resp_rr[:, None]) % b
+    # resp_buf is field-major [F, L, Qr]: lanes on sublanes, slots on lanes
+    resp_full = resp_count >= resp_limit                        # [L, 1]
+    bids_r = (st == S_RESP_PEND) & ~resp_full
+    rot_r = (bank - resp_rr) % b
     key_r = jnp.where(bids_r, rot_r, b)
-    m_r = jnp.min(key_r, axis=1)                                # [L]
+    m_r = jnp.min(key_r, axis=1, keepdims=True)                 # [L, 1]
     any_resp = m_r < b
-    accept_m = bids_r & (rot_r == m_r[:, None])
-    accept = accept_m.reshape(1, total)
+    accept = bids_r & (rot_r == m_r)
     resp_rr2 = jnp.where(any_resp, (resp_rr + m_r + 1) % b, resp_rr)
-    a_i = accept_m.astype(jnp.int32)
-    item = jnp.stack([
-        jnp.sum(a_i * cur_addr.reshape(lanes, b), axis=1),
-        jnp.sum(a_i * cur_write.reshape(lanes, b), axis=1),
-        jnp.sum(a_i * cur_data.reshape(lanes, b), axis=1),
-        jnp.sum(a_i * cur_id.reshape(lanes, b), axis=1),
-    ], axis=1)                                                  # [L, F]
-    old = resp_ref[...].reshape(lanes, qr, nf)
-    widx = (resp_head + resp_count) % qr                        # [L]
+    a_i = accept.astype(jnp.int32)
+    item = [jnp.sum(a_i * v, axis=1, keepdims=True)
+            for v in (cur_addr, cur_write, cur_data, cur_id)]   # F x [L, 1]
+    widx = (resp_head + resp_count) % qr                        # [L, 1]
     qi = jax.lax.broadcasted_iota(jnp.int32, (lanes, qr), 1)
-    at_w = (qi == widx[:, None]) & any_resp[:, None]
-    resp_out_ref[...] = jnp.where(
-        at_w[:, :, None], item[:, None, :], old).reshape(lanes * qr, nf)
+    at_w = (qi == widx) & any_resp
+    head_oh = qi == resp_head
+    head_row = []
+    for f in range(nf):
+        old = resp_ref[f]                                       # [L, Qr]
+        resp_out_ref[f] = jnp.where(at_w, item[f], old)
+        head_row.append(jnp.sum(jnp.where(head_oh, old, 0), axis=1,
+                                keepdims=True))
     resp_count1 = resp_count + any_resp.astype(jnp.int32)
 
     # ---- phase 5: FSM clock edge + bank-queue pop bookkeeping --------------
@@ -344,11 +344,9 @@ def _fused_kernel(topo: Topology, lanes: int, bank_ref, resp_ref, rp_ref,
     qcount2 = qcount - wp
 
     # ---- phase 7: flow-through respQueue ack (Fifo.pop post-push) ----------
-    ack = resp_count1 > 0                                       # [L]
-    head_oh = (qi == resp_head[:, None]).astype(jnp.int32)
-    head_row = jnp.sum(old * head_oh[:, :, None], axis=1)       # [L, F]
-    fitem = jnp.where((any_resp & (widx == resp_head))[:, None],
-                      item, head_row)
+    ack = resp_count1 > 0                                       # [L, 1]
+    flow = any_resp & (widx == resp_head)
+    fitem = [jnp.where(flow, item[f], head_row[f]) for f in range(nf)]
     resp_head2 = (resp_head + ack.astype(jnp.int32)) % qr
     resp_count2 = resp_count1 - ack.astype(jnp.int32)
 
@@ -370,100 +368,55 @@ def _fused_kernel(topo: Topology, lanes: int, bank_ref, resp_ref, rp_ref,
     sref_n = st2 == S_SREF
     bq_valid_n = qcount2 > 0
     inert = in_wait_n | blocked_n | ((idle_n | sref_n) & ~bq_valid_n)
-    gate = jnp.min(inert.astype(jnp.int32).reshape(lanes, b), axis=1) == 1
-    per_bank = jnp.min(jnp.where(blocked_n, legal_n - nxt,
-                                 local).reshape(lanes, b), axis=1)
+    gate = jnp.min(inert.astype(jnp.int32), axis=1, keepdims=True) == 1
+    per_bank = jnp.min(jnp.where(blocked_n, legal_n - nxt, local), axis=1,
+                       keepdims=True)
     # next operating-point boundary is an event (ParamSchedule.next_boundary)
-    bnd = bnd_ref[...].reshape(lanes, -1)
-    nb = jnp.min(jnp.where(bnd > nxt, bnd, SCHEDULE_INF), axis=1)
+    bnd = bnd_ref[...]
+    nb = jnp.min(jnp.where(bnd > nxt, bnd, SCHEDULE_INF), axis=1,
+                 keepdims=True)
     b_val = jnp.minimum(jnp.minimum(per_bank, arrival_rel), horizon - nxt)
     b_val = jnp.minimum(b_val, nb - nxt)
     maybe = (req_count == 0) & (resp_count2 == 0)
-    delta = jnp.where(maybe & gate, jnp.maximum(b_val, 0), 0)   # [L]
+    delta = jnp.where(maybe & gate, jnp.maximum(b_val, 0), 0)   # [L, 1]
 
-    # ---- stores (one [22, L*B] output; row map in the module docstring) ----
-    bank_out_ref[...] = jnp.concatenate(
-        list(new_rows)
-        + [want_pop.astype(jnp.int32), rw_done.astype(jnp.int32),
-           completed.astype(jnp.int32), qhead2, qcount2,
-           la2, aw0_2, aw1_2, aw2_2, aw3_2, lr2, lw2], axis=0)
-    scal_out_ref[...] = jnp.concatenate([
-        jnp.stack([delta, resp_rr2, resp_head2, resp_count2,
-                   ack.astype(jnp.int32)], axis=1),
-        fitem, cmd_rr2, issued,
-    ], axis=1).astype(jnp.int32)
+    # ---- stores (one [22, L, B] output; row map in the module docstring) ---
+    outs = (list(new_rows)
+            + [want_pop.astype(jnp.int32), rw_done.astype(jnp.int32),
+               completed.astype(jnp.int32), qhead2, qcount2,
+               la2, aw0_2, aw1_2, aw2_2, aw3_2, lr2, lw2])
+    for i, row in enumerate(outs):
+        bank_out_ref[i] = row
+    # scalar outputs packed on the lane axis by a select chain (a lane
+    # concatenate of [L, 1] columns is a relayout Mosaic refuses)
+    cols = ([delta, resp_rr2, resp_head2, resp_count2, ack.astype(jnp.int32)]
+            + fitem + cmd_rr2 + cmd_w_c)
+    ko = jax.lax.broadcasted_iota(jnp.int32, scal_out_ref.shape, 1)
+    acc = jnp.zeros(scal_out_ref.shape, jnp.int32)
+    for k, v in enumerate(cols):
+        acc = jnp.where(ko == k, v, acc)
+    scal_out_ref[...] = acc
 
 
 def fused_step_pallas(topo: Topology, bank_rows, resp_buf, rp_mat, bounds,
-                      scal, interpret: bool = True, lanes: int = 1):
+                      scal, interpret: bool):
     """Invoke the fused hot-loop kernel (whole-array blocks, no grid).
 
-    All shape/ordering contracts are in the module docstring.
-    ``bank_rows`` carries ``lanes * topo.num_banks`` lane-major positions
-    on axis 1 (no padding — block width equals the folded bank count; see
-    the split wrappers' ``_block_b`` for why small topologies must not
-    pad). Returns ``(bank_rows2 [22, L*B], resp_buf2, scal2)``."""
+    All shape/ordering contracts are in the module docstring; the lane
+    count L is ``bank_rows.shape[1]``. Returns ``(bank_rows2 [22, L, B],
+    resp_buf2 [F, L, Qr], scal2 [L, 9+2C])``."""
     _count_invocation()
-    total = bank_rows.shape[1]
     assert bank_rows.shape[0] == NUM_BANK_ROWS_IN
-    assert total == lanes * topo.num_banks, (
-        f"bank width {total} != lanes {lanes} * banks {topo.num_banks}")
-    channels = topo.channels
-    kernel = functools.partial(_fused_kernel, topo, lanes)
+    assert bank_rows.shape[2] == topo.num_banks, (
+        f"bank width {bank_rows.shape[2]} != banks {topo.num_banks}")
+    lanes = bank_rows.shape[1]
+    kernel = functools.partial(_fused_kernel, topo)
     out_shape = [
-        jax.ShapeDtypeStruct((NUM_BANK_ROWS_OUT, total), jnp.int32),
+        jax.ShapeDtypeStruct((NUM_BANK_ROWS_OUT,) + bank_rows.shape[1:],
+                             jnp.int32),
         jax.ShapeDtypeStruct(resp_buf.shape, jnp.int32),
-        jax.ShapeDtypeStruct((lanes, NUM_SCAL_OUT + 2 * channels),
+        jax.ShapeDtypeStruct((lanes, NUM_SCAL_OUT + 2 * topo.channels),
                              jnp.int32),
     ]
     return pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret)(
         bank_rows, resp_buf, rp_mat, bounds, scal)
-
-
-def _noninterpret_ok(topo: Topology, num_segments: int, lanes: int) -> bool:
-    """One-shot probe: compile + run this topology's fused kernel with
-    ``interpret=False`` on zero inputs. Cached per (topology, S, L); any
-    failure (no Mosaic/Triton lowering, unsupported gathers on the
-    backend, driver gaps) degrades to interpret mode with a warning
-    instead of crashing mid-sweep."""
-    key = (topo, num_segments, lanes)
-    cached = _FUSED_NONINTERPRET_OK.get(key)
-    if cached is not None:
-        return cached
-    try:
-        from repro.core.params import NUM_RUNTIME_PARAMS
-
-        b = lanes * topo.num_banks
-        z = functools.partial(jnp.zeros, dtype=jnp.int32)
-        out = fused_step_pallas(
-            topo, z((NUM_BANK_ROWS_IN, b)),
-            z((lanes * topo.resp_queue_size, 4)),
-            z((lanes * topo.tiers * num_segments, NUM_RUNTIME_PARAMS)),
-            z((lanes * num_segments, 1)),
-            z((lanes, NUM_SCAL_IN + topo.channels)),
-            interpret=False, lanes=lanes)
-        jax.block_until_ready(out)
-        ok = True
-    except Exception as e:  # noqa: BLE001 - any lowering failure => fall back
-        warnings.warn(
-            f"fused kernel: interpret=False unavailable on backend "
-            f"{jax.default_backend()!r} ({type(e).__name__}); falling back "
-            f"to interpret mode", RuntimeWarning, stacklevel=2)
-        ok = False
-    _FUSED_NONINTERPRET_OK[key] = ok
-    return ok
-
-
-def fused_interpret(topo: Topology, num_segments: int, lanes: int = 1) -> bool:
-    """Interpret-mode decision for the fused kernel: the env override and
-    CPU default of :func:`repro.kernels.bank_fsm.ops.default_interpret`,
-    but with the non-interpret probe compiling *this* kernel for *this*
-    topology and batch width (the fused kernel's segmented reductions and
-    masked scatters are heavier than anything the tiny generic probe can
-    vouch for)."""
-    env = os.environ.get("MEMSIM_PALLAS_INTERPRET", "").strip().lower()
-    if env and env != "auto":
-        return env not in ("0", "false", "no")
-    if jax.default_backend() == "cpu":
-        return True
-    return not _noninterpret_ok(topo, num_segments, lanes)

@@ -3,7 +3,6 @@
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -22,10 +21,6 @@ from repro.kernels.bank_fsm.ref import bank_event_bound_ref, bank_fsm_step_ref
 # tracing would leak that trace's context into later traces
 _FAR_FUTURE = 0x3FFFFFFF
 
-# one-shot probe result: can this process compile+run a Pallas kernel with
-# interpret=False? (None = not probed yet)
-_NONINTERPRET_OK: Optional[bool] = None
-
 
 def _block_b(b: int) -> int:
     """Bank-axis block width: clamp to the actual bank count so small
@@ -35,45 +30,21 @@ def _block_b(b: int) -> int:
     return min(128, b)
 
 
-def _noninterpret_supported() -> bool:
-    """Probe (once) whether interpret=False Pallas compiles and runs on the
-    present jax backend. CPU has no Mosaic/Triton lowering, so this is
-    False there; on TPU/GPU a failure of the tiny probe kernel (missing
-    libtpu features, old drivers ...) also degrades cleanly to interpret
-    mode instead of crashing mid-sweep."""
-    global _NONINTERPRET_OK
-    if _NONINTERPRET_OK is None:
-        try:
-            from jax.experimental import pallas as pl
-
-            def _probe(x_ref, o_ref):
-                o_ref[...] = x_ref[...] + 1
-
-            x = jnp.zeros((8, 128), jnp.int32)
-            out = pl.pallas_call(
-                _probe, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.int32),
-                interpret=False)(x)
-            jax.block_until_ready(out)
-            _NONINTERPRET_OK = True
-        except Exception:  # noqa: BLE001 - any lowering failure => fall back
-            _NONINTERPRET_OK = False
-    return _NONINTERPRET_OK
-
-
 def default_interpret() -> bool:
-    """Pick the Pallas execution mode for this process.
-
-    ``MEMSIM_PALLAS_INTERPRET=1/0`` forces interpret / non-interpret;
-    unset (or ``auto``), interpret mode is used on CPU (where there is no
-    native lowering) and non-interpret on TPU/GPU when the one-shot probe
-    kernel compiles, falling back to interpret otherwise. The result is a
-    plain Python bool baked into the traced program as a static."""
-    env = os.environ.get("MEMSIM_PALLAS_INTERPRET", "").strip().lower()
-    if env and env != "auto":
-        return env not in ("0", "false", "no")
-    if jax.default_backend() == "cpu":
+    """Pallas execution mode, chosen by the platform alone: the
+    interpreter on CPU (which has no Mosaic lowering), the compiled kernel
+    on TPU. Any other platform is an error — there is no fallback, so a
+    kernel that Mosaic refuses fails the run instead of silently timing
+    the interpreter. The result is a plain Python bool baked into the
+    traced program as a static."""
+    platform = jax.default_backend()
+    if platform == "cpu":
         return True
-    return not _noninterpret_supported()
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"no Pallas kernel path for platform {platform!r}: the bank-FSM "
+        "kernels run compiled on TPU and interpreted on CPU only")
 
 
 def _pad_banks(state: Array, inputs: Array, pop: Array, padded_b: int):

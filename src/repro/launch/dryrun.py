@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks device
-# count at first init). 512 placeholder host devices let jax.make_mesh
-# build the production 16x16 single-pod and 2x16x16 multi-pod meshes.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver:
@@ -127,6 +121,10 @@ def main() -> int:
                     help="int8 KV cache for decode cells (perf variant)")
     ap.add_argument("--out", default=None, help="append JSONL records here")
     args = ap.parse_args()
+    # 512 placeholder host devices let jax.make_mesh build the production
+    # 16x16 single-pod and 2x16x16 multi-pod meshes; set here, before the
+    # first backend initializes, and never at import
+    jax.config.update("jax_num_cpu_devices", 512)
 
     cells = []
     if args.all:

@@ -558,7 +558,8 @@ def serving_study(loads: Sequence[float] = (0.5, 1.0, 2.0, 4.0),
     queueing + service percentiles (:func:`repro.core.stats.latency_percentiles`).
     """
     from repro.serving import (ServingConfig, generate_request_batch,
-                               run_serving, run_serving_batched)
+                               run_serving, run_serving_batched,
+                               session_capacity)
 
     serving = serving or ServingConfig()
     if topologies is None:
@@ -581,16 +582,7 @@ def serving_study(loads: Sequence[float] = (0.5, 1.0, 2.0, 4.0),
         seed=seed, independent_streams=False)))
 
     # fixed study-wide capacity -> one compiled program per topology
-    def emissions(reqs):
-        per_req = [(-(-r.prompt_tokens // serving.prefill_tokens_per_step))
-                   * serving.weight_reads_per_token
-                   + r.prompt_tokens * 32
-                   + r.decode_tokens * (serving.weight_reads_per_token
-                                        + serving.kv_reads_per_token + 32)
-                   for r in reqs]
-        return sum(per_req)
-    need = max((emissions(r) for r in scenarios.values()), default=1) + 64
-    capacity = 1 << max(need - 1, 1).bit_length()
+    capacity = session_capacity(scenarios.values(), serving)
 
     rows = []
     for tname, cfg, params in topologies:

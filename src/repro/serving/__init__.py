@@ -27,6 +27,7 @@ from repro.serving.scheduler import (
     plan_window_batch,
     run_serving,
     run_serving_batched,
+    session_capacity,
 )
 from repro.serving.workload import (
     Request,
@@ -48,5 +49,6 @@ __all__ = [
     "plan_window_batch",
     "run_serving",
     "run_serving_batched",
+    "session_capacity",
     "spawn_seeds",
 ]

@@ -260,6 +260,22 @@ class ContinuousBatchScheduler:
         return not self.running and not self.waiting
 
 
+def session_capacity(request_lists, serving: ServingConfig) -> int:
+    """Arrival-slot capacity (a power of two) that holds every DRAM
+    request the scheduler can emit for the largest of ``request_lists``
+    — one shared capacity keeps every session of a study on one compiled
+    windowed program."""
+    def emissions(reqs):
+        return sum((-(-r.prompt_tokens // serving.prefill_tokens_per_step))
+                   * serving.weight_reads_per_token
+                   + r.prompt_tokens * 32
+                   + r.decode_tokens * (serving.weight_reads_per_token
+                                        + serving.kv_reads_per_token + 32)
+                   for r in reqs)
+    need = max((emissions(r) for r in request_lists), default=1) + 64
+    return 1 << max(need - 1, 1).bit_length()
+
+
 def run_serving(cfg, requests: List[Request],
                 serving: Optional[ServingConfig] = None, *,
                 params=None, pager: Optional[KVPager] = None,

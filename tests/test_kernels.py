@@ -261,6 +261,23 @@ def test_fused_kernel_one_dispatch_per_cycle():
     assert bf.trace_invocation_count() - before == 1
 
 
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_platform_alone_picks_interpret_mode(monkeypatch, platform,
+                                             interpret):
+    """The interpreter on CPU, the compiled kernel on TPU, and an error on
+    any other platform: no probe, no override, no fallback."""
+    from repro.kernels.bank_fsm import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    monkeypatch.setenv("MEMSIM_PALLAS_INTERPRET", "1")   # no longer read
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops.default_interpret()
+    else:
+        assert ops.default_interpret() is interpret
+
+
 # ------------------------------------------------------------- addr_map ----
 
 @pytest.mark.parametrize("n", [64, 1000, 4096])

@@ -133,6 +133,13 @@ def test_mesh_pjit_integration_with_per_device_throughput():
                            tr, num_cycles=2000)
             np.testing.assert_array_equal(ref.t_complete, res.t_complete, q)
             np.testing.assert_array_equal(ref.rdata, res.rdata, q)
+        # the per-cycle scan batch is split over the mesh the same way
+        scan = simulate_batch(cfg, tr, num_cycles=2000,
+                              queue_sizes=[4, 8, 16, 32], batch_mode="vmap",
+                              cycle_skip=False)
+        for q, res, sres in zip([4, 8, 16, 32], batch, scan):
+            np.testing.assert_array_equal(res.t_complete, sres.t_complete, q)
+            np.testing.assert_array_equal(res.rdata, sres.rdata, q)
 
         # (3) lanes mode: per-lane device attribution -> per-device
         # throughput; both devices must serve lanes

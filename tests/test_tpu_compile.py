@@ -1,0 +1,157 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e.
+
+Interpret mode on CPU runs every kernel, but never through Mosaic, the
+TPU kernel compiler: a reshape across the lane axis or a relayout it
+cannot do only shows up here, and so does a kernel XLA would have to
+partition across chips. Each test lowers and compiles one kernel (or one
+whole engine program) for one chip, or all four, of a ``v5e:2x2``
+topology that is described, not attached, and asserts that the kernel is in the compiled program
+(``tpu_custom_call``). Nothing runs; results are the interpret-mode tests'
+job.
+
+The topology is described inside a module fixture — never at import — so
+under several pytest workers only the worker that runs this file loads
+the TPU compiler library.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import MemSimConfig
+from repro.core.params import NUM_RUNTIME_PARAMS, Topology
+from repro.kernels.bank_fsm.fused import (
+    NUM_BANK_ROWS_IN,
+    NUM_SCAL_IN,
+    fused_step_pallas,
+)
+
+
+@pytest.fixture(scope="module")
+def chips():
+    from jax.experimental import topologies
+
+    # the TPU compiler otherwise writes its logs outside the checkout
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield topo.devices
+    # drop every program traced for the described chip, so no later test
+    # in this process can pick up a non-interpret trace
+    jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def one_chip(chips):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(chips[0])
+
+
+def _sds(sharding, shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("channels,tiers,segments,lanes", [
+    (2, 1, 1, 1),
+    (2, 1, 1, 8),
+    (2, 1, 3, 1),
+    (2, 2, 1, 1),
+], ids=["base", "lanes8", "segments3", "tiers2"])
+def test_fused_kernel_compiles(one_chip, channels, tiers, segments, lanes):
+    topo = Topology(channels=channels, tiers=tiers,
+                    cxl_channels=1 if tiers == 2 else 0,
+                    fsm_backend="fused")
+    b = topo.num_banks
+    args = (_sds(one_chip, (NUM_BANK_ROWS_IN, lanes, b)),
+            _sds(one_chip, (4, lanes, topo.resp_queue_size)),
+            _sds(one_chip, (lanes, tiers * segments * NUM_RUNTIME_PARAMS)),
+            _sds(one_chip, (lanes, segments)),
+            _sds(one_chip, (lanes, NUM_SCAL_IN + channels)))
+    fn = jax.jit(functools.partial(fused_step_pallas, topo, interpret=False))
+    _assert_kernel(fn.lower(*args).compile())
+
+
+def test_split_fsm_step_compiles(one_chip):
+    from repro.kernels.bank_fsm.bank_fsm import bank_fsm_step_pallas
+
+    topo = Topology(channels=2)
+    b = topo.num_banks
+    args = (_sds(one_chip, (10, b)), _sds(one_chip, (3, b)),
+            _sds(one_chip, (4, b)), _sds(one_chip, (1, NUM_RUNTIME_PARAMS)),
+            _sds(one_chip, (1, 1)), _sds(one_chip, (1, 1)))
+    fn = jax.jit(functools.partial(bank_fsm_step_pallas, topo,
+                                   block_b=min(128, b), interpret=False))
+    _assert_kernel(fn.lower(*args).compile())
+
+
+def test_split_event_bound_compiles(one_chip):
+    from repro.kernels.bank_fsm.bank_fsm import bank_event_bound_pallas
+
+    b = Topology(channels=2).num_banks
+    args = (_sds(one_chip, (10, b)), _sds(one_chip, (1, NUM_RUNTIME_PARAMS)),
+            _sds(one_chip, (1, 1)), _sds(one_chip, (1, 1)))
+    fn = jax.jit(functools.partial(bank_event_bound_pallas,
+                                   block_b=min(128, b), interpret=False))
+    _assert_kernel(fn.lower(*args).compile())
+
+
+def test_fused_engine_compiles(one_chip, monkeypatch):
+    """The whole single-lane event-horizon engine on the fused backend, at
+    the decode-serving trace's size. The engine asks the platform whether
+    to interpret; the test answers "TPU"."""
+    from repro.core import engine, fused_step
+    from repro.traces.llm_workload import decode_serving_trace
+
+    monkeypatch.setattr(fused_step, "default_interpret", lambda: False)
+    cfg = MemSimConfig(channels=2, queue_size=128, fsm_backend="fused")
+    trace = decode_serving_trace()
+    sched = engine._sched_i32(cfg.runtime())
+    args = jax.tree_util.tree_map(
+        lambda x: _sds(one_chip, np.shape(x)),
+        (trace, jnp.int32(400_000), sched, jnp.int32(cfg.queue_size),
+         jnp.int32(cfg.resp_queue_size)))
+    # a fresh jit wrapper: its traces never mix with the engine's own
+    fn = jax.jit(functools.partial(engine._run_skip_core, cfg.topology()))
+    _assert_kernel(fn.lower(*args).compile())
+
+
+def test_fused_batch_engine_compiles_over_four_chips(chips, monkeypatch):
+    """The lane-batched fused engine with its lanes sharded over the four
+    chips of the described host — the sweep path ``simulate_batch`` takes
+    with several devices. XLA cannot partition a Pallas kernel, so this
+    compiles only because the batch is split per device."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import ParamSchedule, engine, fused_step
+    from repro.traces import BENCHMARKS
+
+    monkeypatch.setattr(fused_step, "default_interpret", lambda: False)
+    cfg = MemSimConfig(channels=2, queue_size=32, fsm_backend="fused")
+    lanes = 8
+    mesh = Mesh(np.asarray(chips), ("data",))
+    tr = BENCHMARKS["trace_example"](n=40, gap=5)
+    stacked, _ = engine.stack_traces([tr] * lanes)
+    scheds = ParamSchedule.stack([engine._sched_i32(cfg.runtime())] * lanes)
+    split, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.int32,
+                                       sharding=split),
+        (stacked, scheds, np.zeros(lanes), np.zeros(lanes)))
+    nc = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    compiled = engine._run_skip_batch_split_jit.lower(
+        mesh, cfg.topology(), args[0], nc, *args[1:]).compile()
+    _assert_kernel(compiled)
