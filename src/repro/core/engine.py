@@ -113,6 +113,7 @@ import numpy as np
 from jax import Array
 
 from repro.core import exec_cache
+from repro.core import tracing
 from repro.core import power as power_lib
 from repro.core.bank_fsm import cycles_until_actionable, wait_mask
 from repro.core.params import (
@@ -142,9 +143,10 @@ _PAD_T = 0x3FFFFFFF  # arrival time for padded trace slots: never due
 
 
 # --------------------------------------------------------------------------
-# event-horizon cycle-skipping
+# event-horizon cycle-skipping (device ops named ``memsim.engine.skip``)
 # --------------------------------------------------------------------------
 
+@jax.named_scope("memsim.engine.skip")
 def _next_event(topo: Topology, sched: ParamSchedule, trace: Trace,
                 state: SimState, nxt: Array, horizon: Array) -> Array:
     """Number of provably-inert cycles starting at cycle ``nxt`` — the
@@ -248,6 +250,7 @@ def _event_bound(topo: Topology, sched: ParamSchedule, trace: Trace,
     return jnp.where(gate, jnp.maximum(b, 0), 0).astype(jnp.int32)
 
 
+@jax.named_scope("memsim.engine.skip")
 def _batch_event_deltas(topo: Topology, traces: Trace,
                         scheds: ParamSchedule, states: SimState,
                         nxt: Array, horizon: Array) -> Array:
@@ -269,6 +272,7 @@ def _batch_event_deltas(topo: Topology, traces: Trace,
         lambda: jnp.zeros((lanes,), jnp.int32))
 
 
+@jax.named_scope("memsim.engine.skip")
 def _apply_skip(topo: Topology, sched: ParamSchedule, state: SimState,
                 delta: Array, nxt: Array) -> SimState:
     """Fast-forward ``delta`` inert cycles starting at ``nxt``, replicating
@@ -679,14 +683,15 @@ def _lane_executable(topo: Topology, n_max: int, num_segments: int,
     sched_s = ParamSchedule(
         boundaries=seg,
         values=RuntimeParams(*([val] * len(RuntimeParams._fields))))
-    t0 = time.perf_counter()
-    if cycle_skip:
-        compiled = _run_skip_jit.lower(topo, tr_s, scal, sched_s, scal,
-                                       scal).compile()
-    else:
-        compiled = _run_scan_jit.lower(topo, tr_s, num_cycles, sched_s, scal,
-                                       scal).compile()
-    compile_s = time.perf_counter() - t0
+    with tracing.span("memsim.engine.compile") as sp:
+        tracing.count("compiles")
+        if cycle_skip:
+            compiled = _run_skip_jit.lower(topo, tr_s, scal, sched_s, scal,
+                                           scal).compile()
+        else:
+            compiled = _run_scan_jit.lower(topo, tr_s, num_cycles, sched_s,
+                                           scal, scal).compile()
+    compile_s = sp.seconds
     with _aot_lock:
         _aot_cache[key] = compiled
     if disk_key is not None:
@@ -1030,9 +1035,10 @@ def _aot_finish(key: tuple, lowered) -> Tuple[object, float]:
     to run from worker threads), then publish to the in-memory cache and,
     when enabled, the persistent on-disk executable cache."""
     mem_key, disk_key = key
-    t0 = time.perf_counter()
-    compiled = lowered.compile()
-    compile_s = time.perf_counter() - t0
+    with tracing.span("memsim.engine.compile") as sp:
+        tracing.count("compiles")
+        compiled = lowered.compile()
+    compile_s = sp.seconds
     with _aot_lock:
         _aot_cache[mem_key] = compiled
     if disk_key is not None:
@@ -1050,6 +1056,16 @@ def aot_cache_stats() -> Dict:
     with _aot_lock:
         mem = _aot_cache.stats()
     return {"memory": mem, "disk": exec_cache.stats()}
+
+
+def compiled_texts() -> List[str]:
+    """The optimized HLO text (``Compiled.as_text()``) of every program in
+    the in-memory AOT cache: what a profiler trace's device ops are named
+    after, each with the ``op_name`` metadata that carries its named
+    scope (``memsim.glue.pre`` ...)."""
+    with _aot_lock:
+        programs = list(_aot_cache._entries.values())
+    return [p.as_text() for p in programs]
 
 
 def _aot_compile(jitted, all_args: tuple, dyn_args: tuple,
@@ -1078,17 +1094,20 @@ def _timed(jitted, all_args: tuple, dyn_args: tuple, static_key: tuple,
            timings: Optional[dict]):
     """Invoke a jitted runner, optionally splitting compile vs run wall time
     via AOT lowering (see :func:`_aot_compile` for the cache contract).
-    ``timings`` (if given) gains ``compile_s`` / ``run_s`` / ``compiles``."""
+    ``timings`` (if given) gains ``compile_s`` / ``run_s`` / ``compiles``,
+    read off the ``memsim.engine.compile`` and ``memsim.engine.run`` spans
+    (:mod:`repro.core.tracing`); the run span covers the dispatch and, with
+    ``timings``, the wait for the result."""
     if timings is None:
-        return jitted(*all_args)
+        with tracing.span("memsim.engine.run"):
+            return jitted(*all_args)
     compiled, compile_s, fresh = _aot_compile(jitted, all_args, dyn_args,
                                               static_key)
-    t1 = time.perf_counter()
-    out = compiled(*dyn_args)
-    jax.block_until_ready(out)
-    t2 = time.perf_counter()
+    with tracing.span("memsim.engine.run") as sp:
+        out = compiled(*dyn_args)
+        jax.block_until_ready(out)
     timings["compile_s"] = timings.get("compile_s", 0.0) + compile_s
-    timings["run_s"] = timings.get("run_s", 0.0) + (t2 - t1)
+    timings["run_s"] = timings.get("run_s", 0.0) + sp.seconds
     timings["compiles"] = timings.get("compiles", 0) + fresh
     return out
 
@@ -1115,35 +1134,47 @@ def simulate_fast(cfg: MemSimConfig, trace: Trace, num_cycles: int = 100_000,
     docstring); pass ``cycle_skip=False`` for the plain compile-once scan.
     ``timings`` (optional dict) receives ``compile_s``, ``run_s``,
     ``compiles`` and ``steps`` (cycle_step executions; < num_cycles when
-    skipping helped).
+    skipping helped). ``compile_s`` and ``run_s`` are filled through
+    :func:`repro.core.tracing.span`: the call records the spans
+    ``memsim.simulate_fast`` > ``memsim.engine.prepare`` /
+    ``memsim.engine.compile`` / ``memsim.engine.run`` /
+    ``memsim.engine.to_result`` (``tracing.recent``).
     """
-    cfg.validate()
-    topo = cfg.topology()
-    sched = _sched_i32(cfg.runtime() if params is None else params)
-    ql = cfg.queue_size if queue_size is None else queue_size
-    rl = cfg.resp_queue_size if resp_queue_size is None else resp_queue_size
-    if not (1 <= ql <= cfg.queue_size):
-        raise ValueError(f"queue_size={ql} not in [1, {cfg.queue_size}]")
-    if not (1 <= rl <= cfg.resp_queue_size):
-        raise ValueError(f"resp_queue_size={rl} not in [1, {cfg.resp_queue_size}]")
-    ql = jnp.int32(ql)
-    rl = jnp.int32(rl)
-    if cycle_skip:
-        nc = jnp.int32(num_cycles)
-        final, steps = _timed(_run_skip_jit, (topo, trace, nc, sched, ql, rl),
-                              (trace, nc, sched, ql, rl), (topo,), timings)
-    else:
-        final, steps = _timed(_run_scan_jit,
-                              (topo, trace, num_cycles, sched, ql, rl),
-                              (trace, sched, ql, rl), (topo, num_cycles),
-                              timings)
-    if timings is not None:
-        timings["steps"] = int(steps)
-    res = state_to_result(cfg, trace, final, num_cycles)
-    label = cfg if params is None else sched.apply_to(cfg)
-    res.cfg = dataclasses.replace(label, queue_size=int(ql),
-                                  resp_queue_size=int(rl))
-    return res
+    with tracing.span("memsim.simulate_fast"):
+        with tracing.span("memsim.engine.prepare"):
+            cfg.validate()
+            topo = cfg.topology()
+            sched = _sched_i32(cfg.runtime() if params is None else params)
+            ql = cfg.queue_size if queue_size is None else queue_size
+            rl = (cfg.resp_queue_size if resp_queue_size is None
+                  else resp_queue_size)
+            if not (1 <= ql <= cfg.queue_size):
+                raise ValueError(
+                    f"queue_size={ql} not in [1, {cfg.queue_size}]")
+            if not (1 <= rl <= cfg.resp_queue_size):
+                raise ValueError(
+                    f"resp_queue_size={rl} not in [1, {cfg.resp_queue_size}]")
+            ql = jnp.int32(ql)
+            rl = jnp.int32(rl)
+            nc = jnp.int32(num_cycles) if cycle_skip else None
+        if cycle_skip:
+            final, steps = _timed(_run_skip_jit,
+                                  (topo, trace, nc, sched, ql, rl),
+                                  (trace, nc, sched, ql, rl), (topo,),
+                                  timings)
+        else:
+            final, steps = _timed(_run_scan_jit,
+                                  (topo, trace, num_cycles, sched, ql, rl),
+                                  (trace, sched, ql, rl), (topo, num_cycles),
+                                  timings)
+        if timings is not None:
+            timings["steps"] = int(steps)
+        with tracing.span("memsim.engine.to_result"):
+            res = state_to_result(cfg, trace, final, num_cycles)
+            label = cfg if params is None else sched.apply_to(cfg)
+            res.cfg = dataclasses.replace(label, queue_size=int(ql),
+                                          resp_queue_size=int(rl))
+        return res
 
 
 def simulate_batch(cfg: MemSimConfig,
@@ -1185,7 +1216,69 @@ def simulate_batch(cfg: MemSimConfig,
         cycle-skipping. Best on CPU, where vmap cannot amortize across the
         batch and joint skipping is held back by the busiest lane.
       * ``"auto"``  — ``"lanes"`` on the CPU backend, ``"vmap"`` otherwise.
+
+    The call records the span ``memsim.simulate_batch`` with
+    ``memsim.engine.prepare``, ``memsim.engine.compile`` (fresh compiles
+    only), ``memsim.engine.run`` and ``memsim.engine.to_result`` under it
+    (``tracing.recent``).
     """
+    with tracing.span("memsim.simulate_batch"):
+        with tracing.span("memsim.engine.prepare"):
+            (topo, batch_mode, trace_list, qs, rs, scheds, ns,
+             batched) = _prepare_batch(cfg, traces, queue_sizes,
+                                       resp_queue_sizes, params, lane_cfgs,
+                                       shard, batch_mode, timings)
+        if not trace_list:
+            return []
+        if batch_mode == "lanes":
+            with tracing.span("memsim.engine.run"):
+                finals, lane_steps = _run_lanes(topo, trace_list, num_cycles,
+                                                scheds, qs, rs, cycle_skip,
+                                                shard, timings)
+            if timings is not None:
+                timings["steps"] = max(lane_steps)
+        else:
+            stacked, sched_stack, ql, rl, mesh = batched
+            if cycle_skip:
+                dyn = (stacked, jnp.int32(num_cycles), sched_stack, ql, rl)
+                if mesh is None:
+                    finals, steps = _timed(_run_skip_batch_jit,
+                                           (topo,) + dyn, dyn, (topo,),
+                                           timings)
+                else:
+                    finals, steps = _timed(_run_skip_batch_split_jit,
+                                           (mesh, topo) + dyn, dyn,
+                                           (mesh, topo), timings)
+            else:
+                dyn = (stacked, sched_stack, ql, rl)
+                statics = (topo, num_cycles)
+                if mesh is None:
+                    finals, steps = _timed(_run_scan_batch_jit,
+                                           (topo, stacked, num_cycles)
+                                           + dyn[1:], dyn, statics, timings)
+                else:
+                    finals, steps = _timed(_run_scan_batch_split_jit,
+                                           (mesh, topo, stacked, num_cycles)
+                                           + dyn[1:], dyn, (mesh,) + statics,
+                                           timings)
+            if timings is not None:
+                timings["steps"] = int(np.max(np.asarray(steps)))
+                # the devices the lane results actually live on
+                timings["devices_used"] = sorted(
+                    d.id for d in finals.t_complete.sharding.device_set)
+        with tracing.span("memsim.engine.to_result"):
+            return _batch_results(cfg, num_cycles, trace_list, scheds, qs,
+                                  rs, ns, lane_cfgs, finals,
+                                  batch_mode == "lanes")
+
+
+def _prepare_batch(cfg, traces, queue_sizes, resp_queue_sizes, params,
+                   lane_cfgs, shard, batch_mode, timings):
+    """Host preparation of :func:`simulate_batch`: validated per-lane
+    depths and schedules, and in ``"vmap"`` mode the stacked, padded and
+    (with several devices) sharded operands ``(stacked traces, stacked
+    schedules, queue limits, resp limits, mesh)``; None in ``"lanes"``
+    mode."""
     cfg.validate()
     topo = cfg.topology()
     if batch_mode not in ("auto", "vmap", "lanes"):
@@ -1203,7 +1296,7 @@ def simulate_batch(cfg: MemSimConfig,
         trace_list = list(traces)
     lanes = len(trace_list)
     if lanes == 0:
-        return []
+        return topo, batch_mode, trace_list, [], [], [], [], None
 
     def _broadcast(vals, default, name, cap):
         if vals is None:
@@ -1234,13 +1327,36 @@ def simulate_batch(cfg: MemSimConfig,
         raise ValueError("lane_cfgs must have one entry per lane")
 
     ns = [int(tr.num_requests) for tr in trace_list]
-
     if batch_mode == "lanes":
-        finals, lane_steps = _run_lanes(topo, trace_list, num_cycles, scheds,
-                                        qs, rs, cycle_skip, shard, timings)
-        if timings is not None:
-            timings["steps"] = max(lane_steps)
-            timings["steps_total"] = sum(lane_steps)
+        return topo, batch_mode, trace_list, qs, rs, scheds, ns, None
+    # pad the batch to a device multiple with sentinel lanes so awkward
+    # grid sizes still shard (GSPMD cannot split a ragged batch axis;
+    # without padding a 5-lane sweep on 4 devices would silently run on
+    # ONE device). Sentinel lanes are inert by construction and dropped
+    # by _batch_results, which reads lanes [0, lanes) only.
+    pad_lanes = _shard_pad(lanes) if shard else 0
+    stacked, _ = stack_traces(trace_list, pad_lanes=pad_lanes)
+    sched_stack = ParamSchedule.stack(scheds + [scheds[0]] * pad_lanes)
+    ql = jnp.asarray(qs + [qs[0]] * pad_lanes, jnp.int32)
+    rl = jnp.asarray(rs + [rs[0]] * pad_lanes, jnp.int32)
+    mesh = None
+    if shard:
+        (stacked, sched_stack, ql, rl), mesh = _maybe_shard(
+            (stacked, sched_stack, ql, rl), lanes + pad_lanes)
+    if timings is not None:
+        timings["pad_lanes"] = timings.get("pad_lanes", 0) + pad_lanes
+        timings["sharded"] = mesh is not None
+        timings["devices"] = len(jax.devices())
+    return (topo, batch_mode, trace_list, qs, rs, scheds, ns,
+            (stacked, sched_stack, ql, rl, mesh))
+
+
+def _batch_results(cfg, num_cycles, trace_list, scheds, qs, rs, ns,
+                   lane_cfgs, finals, per_lane: bool) -> List[SimResult]:
+    """The host :class:`SimResult` of every real lane: one ``device_get``
+    of each lane's final state (``per_lane``, ``"lanes"`` mode) or of the
+    stacked batch."""
+    if per_lane:
         hosts = [jax.device_get(f) for f in finals]
 
         def lane_field(i, name):
@@ -1252,51 +1368,6 @@ def simulate_batch(cfg: MemSimConfig,
         def lane_scalar(i, name):
             return int(getattr(hosts[i], name))
     else:
-        # pad the batch to a device multiple with sentinel lanes so awkward
-        # grid sizes still shard (GSPMD cannot split a ragged batch axis;
-        # without padding a 5-lane sweep on 4 devices would silently run on
-        # ONE device). Sentinel lanes are inert by construction and dropped
-        # below: the result loop reads lanes [0, lanes) only.
-        pad_lanes = _shard_pad(lanes) if shard else 0
-        stacked, _ = stack_traces(trace_list, pad_lanes=pad_lanes)
-        sched_stack = ParamSchedule.stack(scheds + [scheds[0]] * pad_lanes)
-        ql = jnp.asarray(qs + [qs[0]] * pad_lanes, jnp.int32)
-        rl = jnp.asarray(rs + [rs[0]] * pad_lanes, jnp.int32)
-        mesh = None
-        if shard:
-            (stacked, sched_stack, ql, rl), mesh = _maybe_shard(
-                (stacked, sched_stack, ql, rl), lanes + pad_lanes)
-        if timings is not None:
-            timings["pad_lanes"] = timings.get("pad_lanes", 0) + pad_lanes
-            timings["sharded"] = mesh is not None
-            timings["devices"] = len(jax.devices())
-
-        if cycle_skip:
-            dyn = (stacked, jnp.int32(num_cycles), sched_stack, ql, rl)
-            if mesh is None:
-                finals, steps = _timed(_run_skip_batch_jit, (topo,) + dyn,
-                                       dyn, (topo,), timings)
-            else:
-                finals, steps = _timed(_run_skip_batch_split_jit,
-                                       (mesh, topo) + dyn, dyn, (mesh, topo),
-                                       timings)
-        else:
-            dyn = (stacked, sched_stack, ql, rl)
-            statics = (topo, num_cycles)
-            if mesh is None:
-                finals, steps = _timed(_run_scan_batch_jit,
-                                       (topo, stacked, num_cycles) + dyn[1:],
-                                       dyn, statics, timings)
-            else:
-                finals, steps = _timed(_run_scan_batch_split_jit,
-                                       (mesh, topo, stacked, num_cycles)
-                                       + dyn[1:], dyn, (mesh,) + statics,
-                                       timings)
-        if timings is not None:
-            timings["steps"] = int(np.max(np.asarray(steps)))
-            # the devices the lane results actually live on
-            timings["devices_used"] = sorted(
-                d.id for d in finals.t_complete.sharding.device_set)
         host = jax.device_get(finals)
 
         def lane_field(i, name):
@@ -1309,7 +1380,7 @@ def simulate_batch(cfg: MemSimConfig,
             return int(np.asarray(getattr(host, name))[i])
 
     results = []
-    for i in range(lanes):
+    for i in range(len(trace_list)):
         if lane_cfgs is not None:
             lane_cfg = lane_cfgs[i]
         else:
@@ -1466,6 +1537,10 @@ def sweep_grid(cfg: MemSimConfig, trace: Trace,
     vmap-style batched program on its topology's device). Pass
     ``stream=False`` to force the materializing path.
 
+    The call records the span ``memsim.sweep_grid``, with
+    ``memsim.engine.prepare`` (per-point configs and schedules) and
+    :func:`simulate_batch`'s spans under it (``tracing.recent``).
+
     Example::
 
         sweep_grid(MemSimConfig(), trace, {
@@ -1475,45 +1550,50 @@ def sweep_grid(cfg: MemSimConfig, trace: Trace,
             "queue_size": [16, 64],
         })
     """
-    points = grid_points(grid)
-    if stream is None:
-        stream = checkpoint_dir is not None or len(points) >= _stream_threshold()
-    if stream:
-        from repro.core.sweep_stream import stream_sweep
+    with tracing.span("memsim.sweep_grid"):
+        points = grid_points(grid)
+        if stream is None:
+            stream = (checkpoint_dir is not None
+                      or len(points) >= _stream_threshold())
+        if stream:
+            from repro.core.sweep_stream import stream_sweep
 
-        return list(stream_sweep(
-            cfg, trace, grid, num_cycles, capacity=capacity,
-            resp_capacity=resp_capacity, cycle_skip=cycle_skip,
-            chunk_lanes=chunk_lanes,
-            memory_budget_bytes=memory_budget_bytes,
-            checkpoint_dir=checkpoint_dir, resume=resume,
-            timings=timings).results)
-    # per-point full configs: __post_init__ validates the policy strings,
-    # validate() the cross-field constraints (e.g. tREFI > tRFC) the seed
-    # path would enforce — a bad grid point fails here, not silently
-    # in-trace. The "schedule" axis is not a config field: it resolves per
-    # lane against that lane's config (lane_schedule), every segment
-    # validated the same way.
-    lane_cfgs = [dataclasses.replace(
-        cfg, **{k: v for k, v in ov.items() if k != "schedule"}).validate()
-        for ov in points]
-    lane_scheds = [lane_schedule(c, ov.get("schedule"))
-                   for c, ov in zip(lane_cfgs, points)]
-    qs = [c.queue_size for c in lane_cfgs]
-    rs = [c.resp_queue_size for c in lane_cfgs]
-    cap = max(qs) if capacity is None else capacity
-    rcap = max(rs) if resp_capacity is None else resp_capacity
-    if cap < max(qs):
-        raise ValueError("capacity below largest swept queue size")
-    if rcap < max(rs):
-        raise ValueError("resp_capacity below largest swept resp queue size")
-    cfg_cap = dataclasses.replace(cfg, queue_size=cap, resp_queue_size=rcap)
-    return simulate_batch(cfg_cap, trace, num_cycles,
-                          queue_sizes=qs, resp_queue_sizes=rs,
-                          params=lane_scheds,
-                          lane_cfgs=lane_cfgs,
-                          cycle_skip=cycle_skip, shard=shard,
-                          batch_mode=batch_mode, timings=timings)
+            return list(stream_sweep(
+                cfg, trace, grid, num_cycles, capacity=capacity,
+                resp_capacity=resp_capacity, cycle_skip=cycle_skip,
+                chunk_lanes=chunk_lanes,
+                memory_budget_bytes=memory_budget_bytes,
+                checkpoint_dir=checkpoint_dir, resume=resume,
+                timings=timings).results)
+        with tracing.span("memsim.engine.prepare"):
+            # per-point full configs: __post_init__ validates the policy
+            # strings, validate() the cross-field constraints (e.g. tREFI >
+            # tRFC) the seed path would enforce — a bad grid point fails
+            # here, not silently in-trace. The "schedule" axis is not a
+            # config field: it resolves per lane against that lane's config
+            # (lane_schedule), every segment validated the same way.
+            lane_cfgs = [dataclasses.replace(
+                cfg, **{k: v for k, v in ov.items() if k != "schedule"}
+            ).validate() for ov in points]
+            lane_scheds = [lane_schedule(c, ov.get("schedule"))
+                           for c, ov in zip(lane_cfgs, points)]
+            qs = [c.queue_size for c in lane_cfgs]
+            rs = [c.resp_queue_size for c in lane_cfgs]
+            cap = max(qs) if capacity is None else capacity
+            rcap = max(rs) if resp_capacity is None else resp_capacity
+            if cap < max(qs):
+                raise ValueError("capacity below largest swept queue size")
+            if rcap < max(rs):
+                raise ValueError(
+                    "resp_capacity below largest swept resp queue size")
+            cfg_cap = dataclasses.replace(cfg, queue_size=cap,
+                                          resp_queue_size=rcap)
+        return simulate_batch(cfg_cap, trace, num_cycles,
+                              queue_sizes=qs, resp_queue_sizes=rs,
+                              params=lane_scheds,
+                              lane_cfgs=lane_cfgs,
+                              cycle_skip=cycle_skip, shard=shard,
+                              batch_mode=batch_mode, timings=timings)
 
 
 # --------------------------------------------------------------------------

@@ -23,6 +23,14 @@ req/resp queues empty, no arrival, no schedule boundary, horizon cap).
 The skip engines apply it via ``engine._apply_skip``; the per-cycle scan
 engine discards it (it passes ``horizon = cycle + 1`` so the bound clamps
 to 0 anyway).
+
+The glue's device ops carry named scopes, which a profiler trace maps to
+through the compiled program's ``op_name`` metadata:
+``memsim.glue.pre`` (``admit``: trace admission and dispatch into the
+bank queues; ``gather``: the FR-FCFS promotion and the queue-head gather
+with the operand packing) and ``memsim.glue.post`` (``records``: the
+start / complete record scatters; ``memory``: the backing-store write and
+read). Scopes change op metadata only, never the compiled step.
 """
 
 from __future__ import annotations
@@ -52,6 +60,7 @@ from repro.kernels.bank_fsm.ref import pack_state, unpack_state
 _INF = 0x3FFFFFFF
 
 
+@jax.named_scope("memsim.glue.pre")
 def _pre(topo: Topology, sched, trace: Trace, state: SimState, cycle: Array,
          horizon):
     """Per-lane front-end glue + kernel operand packing (single-lane
@@ -72,25 +81,27 @@ def _pre(topo: Topology, sched, trace: Trace, state: SimState, cycle: Array,
     b = topo.num_banks
     nxt = cycle + 1
 
-    (req_q, bank_q, t_admit, t_dispatch, next_arrival, blocked_arrival,
-     blocked_dispatch) = _frontend_phases(topo, trace, state, cycle, rp)
-    bank_q = _promote_frfcfs(topo, rp, bank_q, state.bank.open_row)
-
-    packed = pack_state(state.bank)
-    rob = jnp.arange(b, dtype=jnp.int32) // topo.banks_per_rank
-    aw = state.timing.act_win[rob]                       # [B, 4]
-    # head PEEK in glue (the split kernel's ABI does the same); the pop
-    # bookkeeping runs in-kernel on the qmeta rows
-    pop_items, _ = bank_q.peek_valid()
-    bank_rows = jnp.concatenate([
-        packed,
-        jnp.stack([
-            bank_q.head, bank_q.count,
-            state.timing.last_act[rob], aw[:, 0], aw[:, 1], aw[:, 2],
-            aw[:, 3], state.timing.last_rd[rob], state.timing.last_wr[rob],
-        ]),
-        pop_items.T,
-    ])
+    with jax.named_scope("admit"):
+        (req_q, bank_q, t_admit, t_dispatch, next_arrival, blocked_arrival,
+         blocked_dispatch) = _frontend_phases(topo, trace, state, cycle, rp)
+    with jax.named_scope("gather"):
+        bank_q = _promote_frfcfs(topo, rp, bank_q, state.bank.open_row)
+        packed = pack_state(state.bank)
+        rob = jnp.arange(b, dtype=jnp.int32) // topo.banks_per_rank
+        aw = state.timing.act_win[rob]                   # [B, 4]
+        # head PEEK in glue (the split kernel's ABI does the same); the pop
+        # bookkeeping runs in-kernel on the qmeta rows
+        pop_items, _ = bank_q.peek_valid()
+        bank_rows = jnp.concatenate([
+            packed,
+            jnp.stack([
+                bank_q.head, bank_q.count,
+                state.timing.last_act[rob], aw[:, 0], aw[:, 1], aw[:, 2],
+                aw[:, 3], state.timing.last_rd[rob],
+                state.timing.last_wr[rob],
+            ]),
+            pop_items.T,
+        ])
     bounds, rp_mat = sched.pack()
     # next-arrival distance from nxt, post-admission (what the unfused
     # engine's _next_event reads off the post-edge state)
@@ -112,6 +123,7 @@ def _pre(topo: Topology, sched, trace: Trace, state: SimState, cycle: Array,
     return ops, ctx
 
 
+@jax.named_scope("memsim.glue.post")
 def _post(topo: Topology, n: int, state: SimState, cycle: Array, ctx,
           outs) -> Tuple[SimState, Array]:
     """Per-lane unpack of the kernel outputs + the remaining scalar glue
@@ -147,16 +159,19 @@ def _post(topo: Topology, n: int, state: SimState, cycle: Array, ctx,
     issued_cmds = scal_row[NUM_SCAL_OUT + channels:
                            NUM_SCAL_OUT + 2 * channels]
 
-    # where a bank popped, the FSM latched the popped item into its cur_*
-    # registers this edge, so new cur_id IS the popped request id
-    t_start = state.t_start.at[
-        jnp.where(want_pop, new_bank.cur_id, n)
-    ].set(cycle, mode="drop")
-    mem, rdata = _memory_phase(topo, n, state.bank, state.mem, state.rdata,
-                               rw_done)
-    t_complete = state.t_complete.at[
-        jnp.where(ack_valid, fitem_id, n)
-    ].set(cycle, mode="drop")
+    with jax.named_scope("records"):
+        # where a bank popped, the FSM latched the popped item into its
+        # cur_* registers this edge, so new cur_id IS the popped request id
+        t_start = state.t_start.at[
+            jnp.where(want_pop, new_bank.cur_id, n)
+        ].set(cycle, mode="drop")
+    with jax.named_scope("memory"):
+        mem, rdata = _memory_phase(topo, n, state.bank, state.mem,
+                                   state.rdata, rw_done)
+    with jax.named_scope("records"):
+        t_complete = state.t_complete.at[
+            jnp.where(ack_valid, fitem_id, n)
+        ].set(cycle, mode="drop")
     counters = power_lib.update_counters(
         state.counters, issued_cmds, state.bank.st, seg,
         tier_idx=tier_of_bank(topo) if topo.tiers > 1 else None)

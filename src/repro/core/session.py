@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.engine import _PAD_T, _run_window_jit, _sched_i32, _timed
 from repro.core.params import MemSimConfig
 from repro.core.simulator import SimResult, SimState, Trace, init_state
@@ -263,10 +264,9 @@ class SimSession:
         # since the last window) re-dispatch on the same device buffers
         # instead of re-transferring 4 x capacity words every window
         if self._dev_trace is None:
-            self._dev_trace = Trace(
-                t=jnp.asarray(self._t), addr=jnp.asarray(self._addr),
-                is_write=jnp.asarray(self._is_write),
-                wdata=jnp.asarray(self._wdata))
+            host = (self._t, self._addr, self._is_write, self._wdata)
+            self._dev_trace = Trace(*(jnp.asarray(a) for a in host))
+            tracing.count("upload_bytes", sum(a.nbytes for a in host))
         return self._dev_trace
 
     def advance(self, window_cycles: int,
@@ -278,16 +278,22 @@ class SimSession:
         decides what traffic to emit, and hands it in here. The state
         stays on-device; the one host transfer per window is the
         completion-record slice the report is built from.
+
+        Spans: ``memsim.session.upload`` (the append and the arrival
+        buffer's upload, counter ``upload_bytes``: 0 where the cached
+        device copy is reused), ``memsim.engine.run`` and
+        ``memsim.session.fetch`` (the report's transfer and build).
         """
         if window_cycles < 0:
             raise ValueError(f"window_cycles={window_cycles} must be >= 0")
-        if new_arrivals is not None:
-            self.append(new_arrivals)
         t0 = self._cycle
         t1 = t0 + int(window_cycles)
+        with tracing.span("memsim.session.upload"):
+            if new_arrivals is not None:
+                self.append(new_arrivals)
+            trace = self._device_trace() if t1 > t0 else None
         steps = 0
         if t1 > t0:
-            trace = self._device_trace()
             jt0, jt1 = jnp.int32(t0), jnp.int32(t1)
             args = (trace, jt0, jt1, self._sched, self._state)
             state, steps = _timed(_run_window_jit, (self.topo,) + args,
@@ -295,12 +301,13 @@ class SimSession:
             self._state = state
             self._cycle = t1
             steps = int(steps)
-        # ONE host transfer for the whole report: a stacked device_get of
-        # every field the feedback loop reads, not one get per field
-        t_complete, req_q_len, resp_q_len, admitted, blocked = jax.device_get(
-            report_fetch(self._state))
-        return _build_report(t0, t1, self._n_filled, steps, t_complete,
-                             req_q_len, resp_q_len, admitted, blocked)
+        with tracing.span("memsim.session.fetch"):
+            # ONE host transfer for the whole report: a stacked device_get
+            # of every field the feedback loop reads, not one get per field
+            t_complete, req_q_len, resp_q_len, admitted, blocked = \
+                jax.device_get(report_fetch(self._state))
+            return _build_report(t0, t1, self._n_filled, steps, t_complete,
+                                 req_q_len, resp_q_len, admitted, blocked)
 
     def run_until(self, t_end: int,
                   window_cycles: int) -> Sequence[WindowReport]:
@@ -317,7 +324,12 @@ class SimSession:
         """Host-side result bundle over the filled arrival slots — the
         same surface a monolithic :func:`repro.core.engine.simulate_fast`
         run over :meth:`trace` for ``cycle`` cycles returns (bit-identical
-        to it, per the session exactness contract)."""
+        to it, per the session exactness contract). Span:
+        ``memsim.engine.to_result``."""
+        with tracing.span("memsim.engine.to_result"):
+            return self._result()
+
+    def _result(self) -> SimResult:
         n = self._n_filled
         host = jax.device_get(self._state)
         return SimResult(

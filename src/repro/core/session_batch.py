@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.engine import (
     _PAD_T,
     _run_window_batch_jit,
@@ -228,10 +229,9 @@ class SessionBatch:
         # (drain phases) re-dispatch on the same device buffers instead of
         # re-uploading 4 x lanes x capacity words
         if self._dev_traces is None:
-            self._dev_traces = Trace(
-                t=jnp.asarray(self._t), addr=jnp.asarray(self._addr),
-                is_write=jnp.asarray(self._is_write),
-                wdata=jnp.asarray(self._wdata))
+            host = (self._t, self._addr, self._is_write, self._wdata)
+            self._dev_traces = Trace(*(jnp.asarray(a) for a in host))
+            tracing.count("upload_bytes", sum(a.nbytes for a in host))
         return self._dev_traces
 
     def advance(self, window_cycles: int,
@@ -245,22 +245,29 @@ class SessionBatch:
         window runs — ragged per-lane arrival counts are the normal case.
         One batched dispatch advances all lanes; ONE stacked
         ``device_get`` fetches every lane's report fields.
+
+        Spans: ``memsim.session.upload`` (the appends and the arrival
+        buffers' upload, counter ``upload_bytes``: 16 B x lanes x capacity
+        where any lane appended since the last upload, else 0),
+        ``memsim.engine.run`` and ``memsim.session.fetch`` (the stacked
+        transfer and the reports' build).
         """
         if window_cycles < 0:
             raise ValueError(f"window_cycles={window_cycles} must be >= 0")
-        if new_arrivals is not None:
-            if len(new_arrivals) != self.lanes:
-                raise ValueError(
-                    f"new_arrivals has {len(new_arrivals)} entries for "
-                    f"{self.lanes} lanes")
-            for lane, payload in enumerate(new_arrivals):
-                if payload is not None:
-                    self.append(lane, payload)
         t0 = self._cycle
         t1 = t0 + int(window_cycles)
+        with tracing.span("memsim.session.upload"):
+            if new_arrivals is not None:
+                if len(new_arrivals) != self.lanes:
+                    raise ValueError(
+                        f"new_arrivals has {len(new_arrivals)} entries for "
+                        f"{self.lanes} lanes")
+                for lane, payload in enumerate(new_arrivals):
+                    if payload is not None:
+                        self.append(lane, payload)
+            traces = self._device_traces() if t1 > t0 else None
         steps = jnp.int32(0)
         if t1 > t0:
-            traces = self._device_traces()
             jt0, jt1 = jnp.int32(t0), jnp.int32(t1)
             args = (traces, jt0, jt1, self._scheds, self._states)
             jitted = (_run_window_lanes_jit if self.batch_mode == "lanes"
@@ -269,22 +276,24 @@ class SessionBatch:
                                    (self.topo,), self.timings)
             self._states = states
             self._cycle = t1
-        # ONE stacked host transfer for every lane's report fields AND the
-        # step counts ("lanes" mode: per-lane counts, exactly the numbers
-        # the L standalone sessions would report; "vmap" mode: the shared
-        # joint-clock count, same for every lane)
-        (t_complete, req_q, resp_q, admitted, blocked), steps = \
-            jax.device_get((report_fetch(self._states), steps))
-        steps = np.asarray(steps)
-        per_steps = (steps.astype(np.int64).tolist() if steps.ndim
-                     else [int(steps)] * self.lanes)
-        self.timings["steps"] = self.timings.get("steps", 0) + max(per_steps)
-        return [
-            _build_report(t0, t1, self._n_filled[i], per_steps[i],
-                          t_complete[i], req_q[i], resp_q[i], admitted[i],
-                          blocked[i])
-            for i in range(self.lanes)
-        ]
+        with tracing.span("memsim.session.fetch"):
+            # ONE stacked host transfer for every lane's report fields AND
+            # the step counts ("lanes" mode: per-lane counts, exactly the
+            # numbers the L standalone sessions would report; "vmap" mode:
+            # the shared joint-clock count, same for every lane)
+            (t_complete, req_q, resp_q, admitted, blocked), steps = \
+                jax.device_get((report_fetch(self._states), steps))
+            steps = np.asarray(steps)
+            per_steps = (steps.astype(np.int64).tolist() if steps.ndim
+                         else [int(steps)] * self.lanes)
+            self.timings["steps"] = (self.timings.get("steps", 0)
+                                     + max(per_steps))
+            return [
+                _build_report(t0, t1, self._n_filled[i], per_steps[i],
+                              t_complete[i], req_q[i], resp_q[i],
+                              admitted[i], blocked[i])
+                for i in range(self.lanes)
+            ]
 
     def run_until(self, t_end: int,
                   window_cycles: int) -> List[List[WindowReport]]:
@@ -304,7 +313,13 @@ class SessionBatch:
         standalone :meth:`SimSession.result` over the same arrivals and
         the same final clock. ``num_cycles`` relabels the cycle count for
         lanes that went idle before the batch clock stopped (the state
-        past that point is inert for them)."""
+        past that point is inert for them). Span:
+        ``memsim.engine.to_result``."""
+        with tracing.span("memsim.engine.to_result"):
+            return self._lane_result(lane, num_cycles)
+
+    def _lane_result(self, lane: int,
+                     num_cycles: Optional[int]) -> SimResult:
         n = self._n_filled[lane]
         host = jax.device_get(
             jax.tree_util.tree_map(lambda x: x[lane], self._states))

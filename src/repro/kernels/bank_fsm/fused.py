@@ -418,5 +418,6 @@ def fused_step_pallas(topo: Topology, bank_rows, resp_buf, rp_mat, bounds,
         jax.ShapeDtypeStruct((lanes, NUM_SCAL_OUT + 2 * topo.channels),
                              jnp.int32),
     ]
-    return pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret)(
+    return pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret,
+                          name="fused_fsm")(
         bank_rows, resp_buf, rp_mat, bounds, scal)

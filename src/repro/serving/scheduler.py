@@ -34,11 +34,13 @@ gives the open-loop regime.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from collections import deque
 from typing import Dict, List, Optional, Set
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.session import SimSession, WindowReport
 from repro.core.session_batch import SessionBatch, _per_lane
 from repro.serving.kv_pager import KVPager
@@ -295,39 +297,48 @@ def run_serving(cfg, requests: List[Request],
     program — pass a shared ``timings`` dict across calls to see
     ``compiles`` stay at the topology count.
     """
-    serving = serving or ServingConfig()
-    if pager is None:
-        pager = KVPager(tiered=cfg.tiers > 1,
-                        interleave_log2=cfg.tier_interleave_log2,
-                        cxl_frac_log2=cfg.tier_cxl_frac_log2)
-    session = SimSession.open(cfg, capacity=capacity, params=params,
-                              timings=timings)
-    sched = ContinuousBatchScheduler(serving, pager, requests,
-                                     queue_limit=cfg.queue_size, seed=seed)
-    last_arrival = max((r.arrival for r in requests), default=0)
-    if max_cycles is None:
-        max_cycles = last_arrival + 400 * window_cycles
-    while session.cycle < max_cycles:
-        if sched.idle() and session.cycle > last_arrival:
-            break
-        t0 = session.cycle
-        arrivals = sched.plan_window(t0, t0 + window_cycles)
-        report = session.advance(window_cycles, arrivals)
-        sched.observe(report)
+    with tracing.span("memsim.serve"):
+        serving = serving or ServingConfig()
+        if pager is None:
+            pager = KVPager(tiered=cfg.tiers > 1,
+                            interleave_log2=cfg.tier_interleave_log2,
+                            cxl_frac_log2=cfg.tier_cxl_frac_log2)
+        with tracing.span("memsim.engine.prepare"):
+            session = SimSession.open(cfg, capacity=capacity, params=params,
+                                      timings=timings)
+            sched = ContinuousBatchScheduler(serving, pager, requests,
+                                             queue_limit=cfg.queue_size,
+                                             seed=seed)
+        last_arrival = max((r.arrival for r in requests), default=0)
+        if max_cycles is None:
+            max_cycles = last_arrival + 400 * window_cycles
+        window = 0
+        while session.cycle < max_cycles:
+            if sched.idle() and session.cycle > last_arrival:
+                break
+            with tracing.span("memsim.serve.window", window=window):
+                t0 = session.cycle
+                with tracing.span("memsim.serve.plan"):
+                    arrivals = sched.plan_window(t0, t0 + window_cycles)
+                report = session.advance(window_cycles, arrivals)
+                with tracing.span("memsim.serve.observe"):
+                    sched.observe(report)
+            window += 1
 
-    done = [s for s in sched.finished if s.done_at >= 0]
-    return ServingResult(
-        offered=len(requests),
-        completed=len(done),
-        tokens=sched.tokens,
-        cycles=session.cycle,
-        admitted_batch=sched.admitted_batch,
-        batch_target=sched.batch_target,
-        queueing=np.asarray([s.joined - s.req.arrival for s in done],
-                            np.int64),
-        service=np.asarray([s.done_at - s.joined for s in done], np.int64),
-        session=session,
-    )
+        done = [s for s in sched.finished if s.done_at >= 0]
+        return ServingResult(
+            offered=len(requests),
+            completed=len(done),
+            tokens=sched.tokens,
+            cycles=session.cycle,
+            admitted_batch=sched.admitted_batch,
+            batch_target=sched.batch_target,
+            queueing=np.asarray([s.joined - s.req.arrival for s in done],
+                                np.int64),
+            service=np.asarray([s.done_at - s.joined for s in done],
+                               np.int64),
+            session=session,
+        )
 
 
 # --------------------------------------------------------------------------
@@ -339,11 +350,12 @@ def plan_window_batch(scheds: List[ContinuousBatchScheduler], t0: int,
     """One ``plan_window`` per *live* lane — the per-lane arrival payload
     list :meth:`repro.core.SessionBatch.advance` takes (drained lanes get
     ``None`` and emit nothing, exactly like their sequential run after its
-    loop exited)."""
+    loop exited). Span: ``memsim.serve.plan``."""
     if active is None:
         active = [True] * len(scheds)
-    return [s.plan_window(t0, t1) if live else None
-            for s, live in zip(scheds, active)]
+    with tracing.span("memsim.serve.plan"):
+        return [s.plan_window(t0, t1) if live else None
+                for s, live in zip(scheds, active)]
 
 
 def observe_batch(scheds: List[ContinuousBatchScheduler],
@@ -352,12 +364,14 @@ def observe_batch(scheds: List[ContinuousBatchScheduler],
     """Fold one batched window's per-lane reports back into each live
     lane's scheduler. The reports all come from a SINGLE stacked
     ``device_get`` inside ``SessionBatch.advance`` — one host transfer
-    per window for the whole grid, not one per lane per field."""
+    per window for the whole grid, not one per lane per field. Span:
+    ``memsim.serve.observe``."""
     if active is None:
         active = [True] * len(scheds)
-    for s, rep, live in zip(scheds, reports, active):
-        if live:
-            s.observe(rep)
+    with tracing.span("memsim.serve.observe"):
+        for s, rep, live in zip(scheds, reports, active):
+            if live:
+                s.observe(rep)
 
 
 def run_serving_batched(cfg, request_lists: List[List[Request]],
@@ -388,57 +402,64 @@ def run_serving_batched(cfg, request_lists: List[List[Request]],
     ``timings["compiles"]`` counts 1 per distinct
     ``(topology, capacity, lanes, segments)``.
     """
-    serving = serving or ServingConfig()
-    lanes = len(request_lists)
-    if lanes < 1:
-        raise ValueError("request_lists must name at least one lane")
-    if pagers is None:
-        pagers = [KVPager(tiered=cfg.tiers > 1,
-                          interleave_log2=cfg.tier_interleave_log2,
-                          cxl_frac_log2=cfg.tier_cxl_frac_log2)
-                  for _ in range(lanes)]
-    elif len(pagers) != lanes:
-        raise ValueError(f"{len(pagers)} pagers for {lanes} lanes")
-    lane_seeds = _per_lane(seed if seeds is None else seeds, lanes, "seeds")
-    batch = SessionBatch.open(cfg, lanes, capacity=capacity, params=params,
-                              batch_mode=batch_mode, timings=timings)
-    scheds = [ContinuousBatchScheduler(serving, pagers[i], request_lists[i],
-                                       queue_limit=cfg.queue_size,
-                                       seed=lane_seeds[i])
-              for i in range(lanes)]
-    last_arrival = [max((r.arrival for r in reqs), default=0)
-                    for reqs in request_lists]
-    lane_max = [(la + 400 * window_cycles if max_cycles is None
-                 else max_cycles) for la in last_arrival]
-    done_cycle: List[Optional[int]] = [None] * lanes
-    while True:
-        t0 = batch.cycle
-        for i in range(lanes):
-            if done_cycle[i] is None and (
-                    t0 >= lane_max[i]
-                    or (scheds[i].idle() and t0 > last_arrival[i])):
-                done_cycle[i] = t0
-        active = [d is None for d in done_cycle]
-        if not any(active):
-            break
-        arrivals = plan_window_batch(scheds, t0, t0 + window_cycles, active)
-        reports = batch.advance(window_cycles, arrivals)
-        observe_batch(scheds, reports, active)
+    with tracing.span("memsim.serve"):
+        serving = serving or ServingConfig()
+        lanes = len(request_lists)
+        if lanes < 1:
+            raise ValueError("request_lists must name at least one lane")
+        if pagers is None:
+            pagers = [KVPager(tiered=cfg.tiers > 1,
+                              interleave_log2=cfg.tier_interleave_log2,
+                              cxl_frac_log2=cfg.tier_cxl_frac_log2)
+                      for _ in range(lanes)]
+        elif len(pagers) != lanes:
+            raise ValueError(f"{len(pagers)} pagers for {lanes} lanes")
+        lane_seeds = _per_lane(seed if seeds is None else seeds, lanes,
+                               "seeds")
+        with tracing.span("memsim.engine.prepare"):
+            batch = SessionBatch.open(cfg, lanes, capacity=capacity,
+                                      params=params, batch_mode=batch_mode,
+                                      timings=timings)
+            scheds = [ContinuousBatchScheduler(serving, pagers[i],
+                                               request_lists[i],
+                                               queue_limit=cfg.queue_size,
+                                               seed=lane_seeds[i])
+                      for i in range(lanes)]
+        last_arrival = [max((r.arrival for r in reqs), default=0)
+                        for reqs in request_lists]
+        lane_max = [(la + 400 * window_cycles if max_cycles is None
+                     else max_cycles) for la in last_arrival]
+        done_cycle: List[Optional[int]] = [None] * lanes
+        for window in itertools.count():
+            t0 = batch.cycle
+            for i in range(lanes):
+                if done_cycle[i] is None and (
+                        t0 >= lane_max[i]
+                        or (scheds[i].idle() and t0 > last_arrival[i])):
+                    done_cycle[i] = t0
+            active = [d is None for d in done_cycle]
+            if not any(active):
+                break
+            with tracing.span("memsim.serve.window", window=window):
+                arrivals = plan_window_batch(scheds, t0, t0 + window_cycles,
+                                             active)
+                reports = batch.advance(window_cycles, arrivals)
+                observe_batch(scheds, reports, active)
 
-    results = []
-    for i in range(lanes):
-        done = [s for s in scheds[i].finished if s.done_at >= 0]
-        results.append(ServingResult(
-            offered=len(request_lists[i]),
-            completed=len(done),
-            tokens=scheds[i].tokens,
-            cycles=done_cycle[i],
-            admitted_batch=scheds[i].admitted_batch,
-            batch_target=scheds[i].batch_target,
-            queueing=np.asarray([s.joined - s.req.arrival for s in done],
-                                np.int64),
-            service=np.asarray([s.done_at - s.joined for s in done],
-                               np.int64),
-            session=batch.lane_view(i, done_cycle[i]),
-        ))
-    return results
+        results = []
+        for i in range(lanes):
+            done = [s for s in scheds[i].finished if s.done_at >= 0]
+            results.append(ServingResult(
+                offered=len(request_lists[i]),
+                completed=len(done),
+                tokens=scheds[i].tokens,
+                cycles=done_cycle[i],
+                admitted_batch=scheds[i].admitted_batch,
+                batch_target=scheds[i].batch_target,
+                queueing=np.asarray(
+                    [s.joined - s.req.arrival for s in done], np.int64),
+                service=np.asarray([s.done_at - s.joined for s in done],
+                                   np.int64),
+                session=batch.lane_view(i, done_cycle[i]),
+            ))
+        return results
