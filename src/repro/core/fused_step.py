@@ -27,10 +27,11 @@ to 0 anyway).
 The glue's device ops carry named scopes, which a profiler trace maps to
 through the compiled program's ``op_name`` metadata:
 ``memsim.glue.pre`` (``admit``: trace admission and dispatch into the
-bank queues; ``gather``: the FR-FCFS promotion and the queue-head gather
-with the operand packing) and ``memsim.glue.post`` (``records``: the
-start / complete record scatters; ``memory``: the backing-store write and
-read). Scopes change op metadata only, never the compiled step.
+bank queues; ``gather``: the FR-FCFS promotion, which searches each bank
+ring where it lies, then the queue-head gather with the operand packing)
+and ``memsim.glue.post`` (``records``: the start / complete record
+scatters; ``memory``: the backing-store write and read). Scopes change op
+metadata only, never the compiled step.
 """
 
 from __future__ import annotations

@@ -179,30 +179,77 @@ class BankedFifo(NamedTuple):
         row-hit entry into the head slot so the scheduler issues it next.
 
         ``open_row`` int32[B] (-1 = no open row); ``rows`` int32[B, Q] row
-        index of every queue slot in AGE order (oldest first). An entry is
-        only promoted if no older entry touches the same address (program
-        order per address must hold — real controllers enforce the same
-        dependency check).
+        index of every queue slot where it lies in the ring (physical slot
+        order, not age order). An entry is only promoted if no older entry
+        touches the same address (program order per address must hold —
+        real controllers enforce the same dependency check).
+
+        The search works on the ring in place: every slot knows its age
+        ``(slot - head) % Q`` and the chosen entry's address is read by a
+        masked reduction, so nothing rotates the ring into age order (a
+        [B, Q] gather, and under ``vmap`` an [L, B, Q] one). Without a
+        promotable hit ``sel`` is 0 and the swap is the identity.
         """
-        b, q, _ = self.buf.shape
-        ar_b = jnp.arange(b)
-        offs = (self.head[:, None] + jnp.arange(q)[None, :]) % q     # [B, Q]
-        addr = jnp.take_along_axis(self.buf[..., F_ADDR], offs, axis=1)
-        valid = jnp.arange(q)[None, :] < self.count[:, None]
+        q = self.capacity
+        slot = jnp.arange(q, dtype=jnp.int32)[None, :]               # [1, Q]
+        age = (slot - self.head[:, None]) % q                        # [B, Q]
+        valid = age < self.count[:, None]
         hit = valid & (rows == open_row[:, None]) & (open_row >= 0)[:, None]
-        first = jnp.argmax(hit, axis=1).astype(jnp.int32)            # [B]
-        has = hit.any(axis=1)
+        first = jnp.min(jnp.where(hit, age, q), axis=1)              # [B]
+        has = first < q
         # dependency guard: an older same-address entry blocks promotion
-        addr_sel = jnp.take_along_axis(addr, first[:, None], axis=1)[:, 0]
-        older = jnp.arange(q)[None, :] < first[:, None]
-        conflict = (older & valid & (addr == addr_sel[:, None])).any(axis=1)
+        addr = self.buf[..., F_ADDR]
+        addr_sel = jnp.sum(jnp.where(age == first[:, None], addr, 0), axis=1)
+        # (older than a hit means valid: first < count wherever has)
+        older = age < first[:, None]
+        conflict = (older & (addr == addr_sel[:, None])).any(axis=1)
         sel = jnp.where(has & ~conflict, first, 0)
         pos = (self.head + sel) % q
-        head_items = self.buf[ar_b, self.head]
-        sel_items = self.buf[ar_b, pos]
-        buf = self.buf.at[ar_b, self.head].set(sel_items)
-        buf = buf.at[ar_b, pos].set(head_items)
+        buf = _swap_into_head(self.buf, self.head, pos)
         return BankedFifo(buf, self.head, self.count, self.limit)
+
+
+def _swap_rows(buf: Array, head: Array, pos: Array) -> Array:
+    """Swap slot ``pos[b]`` with slot ``head[b]`` of every bank queue by
+    per-bank row reads and writes."""
+    ar_b = jnp.arange(buf.shape[0])
+    head_items = buf[ar_b, head]
+    sel_items = buf[ar_b, pos]
+    buf = buf.at[ar_b, head].set(sel_items)
+    return buf.at[ar_b, pos].set(head_items)
+
+
+def _swap_onehot(buf: Array, head: Array, pos: Array) -> Array:
+    """:func:`_swap_rows` as a dense one-hot select over the ring."""
+    slot = jnp.arange(buf.shape[1], dtype=jnp.int32)[None, :]
+    at_head = (slot == head[:, None])[..., None]                  # [B, Q, 1]
+    at_pos = (slot == pos[:, None])[..., None]
+    head_item = jnp.sum(jnp.where(at_head, buf, 0), axis=1)       # [B, F]
+    sel_item = jnp.sum(jnp.where(at_pos, buf, 0), axis=1)
+    # pos == head takes the at_head arm, where sel_item is the head item
+    return jnp.where(at_head, sel_item[:, None, :],
+                     jnp.where(at_pos, head_item[:, None, :], buf))
+
+
+@jax.custom_batching.custom_vmap
+def _swap_into_head(buf: Array, head: Array, pos: Array) -> Array:
+    """The promotion's swap, in the form that suits how it is batched.
+
+    One lane swaps rows: a dense select over the ring makes XLA lay the
+    carried queue buffer out slot-minor, and the single-lane step then
+    copies it back for the head peek on every step, FR-FCFS or not (8 % of
+    the one-lane decode rate on a TPU v5e). Under ``vmap`` the row writes
+    become scatters over the whole [L, B, Q, F] buffer; the one-hot select,
+    which fuses with the policy's select, runs a 64-lane sweep 1.17x and
+    8 serving lanes 1.5x as fast end to end on a TPU v5e."""
+    return _swap_rows(buf, head, pos)
+
+
+@_swap_into_head.def_vmap
+def _swap_into_head_vmap(axis_size, in_batched, buf, head, pos):
+    args = [x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, batched in zip((buf, head, pos), in_batched)]
+    return jax.vmap(_swap_onehot)(*args), True
 
 
 def rr_arbiter(bids: Array, ptr: Array) -> Tuple[Array, Array, Array]:

@@ -48,7 +48,13 @@ from repro.core.params import (
     rp_for_banks,
     tier_of_bank,
 )
-from repro.core.queues import BankedFifo, Fifo, rr_arbiter, rr_arbiter_grouped
+from repro.core.queues import (
+    F_ADDR,
+    BankedFifo,
+    Fifo,
+    rr_arbiter,
+    rr_arbiter_grouped,
+)
 
 
 class Trace(NamedTuple):
@@ -256,17 +262,17 @@ def _frontend_phases(topo: Topology, trace: Trace, state: SimState,
 def _promote_frfcfs(topo: Topology, rp, bank_q: BankedFifo,
                     open_row: Array) -> BankedFifo:
     """FR-FCFS (a traced policy flag): promote the oldest row-hit to each
-    bank queue's head. lax.cond keeps the promotion network off the
-    runtime path for FCFS lanes on the single-lane engines (under vmap it
-    lowers to a select, which is the price of a shared program). Shared by
-    :func:`cycle_step` and the fused step."""
+    bank queue's head. On the single-lane engines lax.cond is a real
+    branch, so an FCFS lane runs none of the promotion; under vmap it
+    lowers to a select between the promoted and the unchanged buffer,
+    which fuses with the batched swap's one-hot select into one pass over
+    the buffer (``queues._swap_into_head``). Shared by :func:`cycle_step`
+    and the fused step."""
     from repro.core.bank_fsm import row_of
 
     def _promoted_buf():
-        q = bank_q.capacity
-        offs = (bank_q.head[:, None] + jnp.arange(q)[None, :]) % q
-        addrs = jnp.take_along_axis(bank_q.buf[..., 0], offs, axis=1)
-        return bank_q.promote_rowhit(open_row, row_of(topo, addrs)).buf
+        rows = row_of(topo, bank_q.buf[..., F_ADDR])
+        return bank_q.promote_rowhit(open_row, rows).buf
 
     pol = jnp.asarray(rp.sched_policy)
     if topo.tiers > 1:

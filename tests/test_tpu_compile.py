@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -155,3 +156,40 @@ def test_fused_batch_engine_compiles_over_four_chips(chips, monkeypatch):
     compiled = engine._run_skip_batch_split_jit.lower(
         mesh, cfg.topology(), args[0], nc, *args[1:]).compile()
     _assert_kernel(compiled)
+
+
+def _gather_sizes(text):
+    """Output element counts of every gather in a compiled HLO text
+    (fused computations included)."""
+    sizes = []
+    for dims in re.findall(r"= \w+\[([\d,]*)\]\{[^}]*\} gather\(", text):
+        sizes.append(int(np.prod([int(d) for d in dims.split(",") if d])))
+    return sizes
+
+
+def test_batched_fused_engine_has_no_queue_wide_gather(one_chip, monkeypatch):
+    """The lane-batched fused engine at the 64-lane sweep's shape (64
+    lanes, 64 banks, queues of 128): the FR-FCFS promotion searches and
+    swaps on the ring where it lies, so no gather reads every slot of
+    every bank queue of every lane (an [L, B, Q] gather, which the chip
+    runs at ~10 ns an element, every step)."""
+    from repro.core import ParamSchedule, engine, fused_step
+    from repro.traces import BENCHMARKS
+
+    monkeypatch.setattr(fused_step, "default_interpret", lambda: False)
+    cfg = MemSimConfig(channels=2, queue_size=128, fsm_backend="fused")
+    topo = cfg.topology()
+    lanes = 64
+    tr = BENCHMARKS["trace_example"](n=40, gap=5)
+    stacked, _ = engine.stack_traces([tr] * lanes)
+    scheds = ParamSchedule.stack([engine._sched_i32(cfg.runtime())] * lanes)
+    args = jax.tree_util.tree_map(
+        lambda x: _sds(one_chip, np.shape(x)),
+        (stacked, jnp.int32(100_000), scheds, np.zeros(lanes),
+         np.zeros(lanes)))
+    fn = jax.jit(functools.partial(engine._run_skip_batch_core, topo))
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    sizes = _gather_sizes(text)
+    assert sizes, "no gather found: the HLO text pattern no longer matches"
+    assert lanes * topo.num_banks * cfg.queue_size not in sizes, sizes
