@@ -157,7 +157,9 @@ def _next_event(topo: Topology, sched: ParamSchedule, trace: Trace,
     of: a timed WAIT state (timer merely decrements), parked in SREF, idle
     with an empty scheduler queue, or holding an ISSUE-state bid whose
     command is not yet legal under the rank timing windows
-    (tRRDL/tFAW/tCCDL/tWTR/tRTW) — judged by the same
+    (tRRDL/tFAW/tCCDL/tWTR/tRTW; under ``timing_model="jedec"`` the S
+    windows over the rank, the L windows over the bank group, and a PRE's
+    precharge gate) — judged by the same
     :func:`issue_eligibility` predicate ``cycle_step`` grants from, so
     "blocked" here and "not granted" there can never disagree. Globally the
     request and response queues must be empty (a dispatch, admission or ack
@@ -931,7 +933,7 @@ _aot_cache = _AotLruCache()
 _aot_lock = threading.Lock()
 
 
-def _rp_i32(rp: RuntimeParams) -> RuntimeParams:
+def _rp_i32(rp: RuntimeParams, timing_model: str = "table1") -> RuntimeParams:
     """Coerce every RuntimeParams leaf to a committed int32 scalar so AOT
     cache keys and lowered signatures are stable regardless of whether the
     caller passed Python ints or device arrays. The cross-field constraints
@@ -949,13 +951,22 @@ def _rp_i32(rp: RuntimeParams) -> RuntimeParams:
         except (TypeError, jax.errors.TracerIntegerConversionError,
                 jax.errors.ConcretizationTypeError):
             vals[f] = None  # traced leaf
-    bad = runtime_constraint_violations(vals)
+    bad = runtime_constraint_violations(vals, timing_model)
     if bad:
         raise ValueError("; ".join(bad))
-    return RuntimeParams(*[jnp.asarray(v, jnp.int32) for v in rp])
+    return RuntimeParams(*[_i32(v) for v in rp])
 
 
-def _sched_i32(params) -> ParamSchedule:
+def _i32(x):
+    """``x`` as an int32 array: a numpy one for a host value
+    (``params.on_host``), so preparing a lane's parameters dispatches
+    nothing to the device; else a jax one."""
+    from repro.core.params import on_host
+
+    return np.asarray(x, np.int32) if on_host(x) else jnp.asarray(x, jnp.int32)
+
+
+def _sched_i32(params, timing_model: str = "table1") -> ParamSchedule:
     """Canonicalize a ``params=`` override to a validated int32
     :class:`ParamSchedule`: a bare :class:`RuntimeParams` lifts to the S=1
     degenerate schedule through :func:`_rp_i32` (same committed-leaf and
@@ -965,13 +976,12 @@ def _sched_i32(params) -> ParamSchedule:
     construction (traced leaves are skipped; the caller inside the trace
     owns those)."""
     if isinstance(params, RuntimeParams):
-        return ParamSchedule.constant(_rp_i32(params))
+        return ParamSchedule.constant(_rp_i32(params, timing_model))
     sched = as_schedule(params)  # raises TypeError on anything else
-    sched.validate()
-    return ParamSchedule(
-        boundaries=jnp.asarray(sched.boundaries, jnp.int32),
-        values=RuntimeParams(
-            *[jnp.asarray(v, jnp.int32) for v in sched.values]))
+    sched.validate(timing_model)
+    return ParamSchedule(boundaries=_i32(sched.boundaries),
+                         values=RuntimeParams(*[_i32(v)
+                                                for v in sched.values]))
 
 
 def _jit_name(jitted) -> str:
@@ -1144,7 +1154,8 @@ def simulate_fast(cfg: MemSimConfig, trace: Trace, num_cycles: int = 100_000,
         with tracing.span("memsim.engine.prepare"):
             cfg.validate()
             topo = cfg.topology()
-            sched = _sched_i32(cfg.runtime() if params is None else params)
+            sched = _sched_i32(cfg.runtime() if params is None else params,
+                               cfg.timing_model)
             ql = cfg.queue_size if queue_size is None else queue_size
             rl = (cfg.resp_queue_size if resp_queue_size is None
                   else resp_queue_size)
@@ -1174,6 +1185,7 @@ def simulate_fast(cfg: MemSimConfig, trace: Trace, num_cycles: int = 100_000,
             label = cfg if params is None else sched.apply_to(cfg)
             res.cfg = dataclasses.replace(label, queue_size=int(ql),
                                           resp_queue_size=int(rl))
+            count_grants([res])
         return res
 
 
@@ -1267,9 +1279,11 @@ def simulate_batch(cfg: MemSimConfig,
                 timings["devices_used"] = sorted(
                     d.id for d in finals.t_complete.sharding.device_set)
         with tracing.span("memsim.engine.to_result"):
-            return _batch_results(cfg, num_cycles, trace_list, scheds, qs,
-                                  rs, ns, lane_cfgs, finals,
-                                  batch_mode == "lanes")
+            results = _batch_results(cfg, num_cycles, trace_list, scheds,
+                                     qs, rs, ns, lane_cfgs, finals,
+                                     batch_mode == "lanes")
+            count_grants(results)
+            return results
 
 
 def _prepare_batch(cfg, traces, queue_sizes, resp_queue_sizes, params,
@@ -1314,9 +1328,9 @@ def _prepare_batch(cfg, traces, queue_sizes, resp_queue_sizes, params,
     rs = _broadcast(resp_queue_sizes, cfg.resp_queue_size,
                     "resp_queue_sizes", cfg.resp_queue_size)
     if params is None:
-        scheds = [_sched_i32(cfg.runtime())] * lanes
+        scheds = [_sched_i32(cfg.runtime(), cfg.timing_model)] * lanes
     else:
-        scheds = [_sched_i32(p) for p in params]
+        scheds = [_sched_i32(p, cfg.timing_model) for p in params]
         if len(scheds) != lanes:
             raise ValueError("params must have one entry per lane")
     # mixed constant/schedule lanes share one compiled program: pad every
@@ -1349,6 +1363,32 @@ def _prepare_batch(cfg, traces, queue_sizes, resp_queue_sizes, params,
         timings["devices"] = len(jax.devices())
     return (topo, batch_mode, trace_list, qs, rs, scheds, ns,
             (stacked, sched_stack, ql, rl, mesh))
+
+
+#: span counter -> the result counter it sums over a call's lanes
+GRANT_SPAN_COUNTERS = {"jedec_l_bound": "grants_l_bound",
+                       "jedec_windowed": "grants_windowed",
+                       "jedec_pre_held": "pre_held"}
+
+
+def count_grants(results: Sequence[SimResult]) -> None:
+    """Add the ``"jedec"`` grant counters of ``results``, summed over the
+    lanes, to the open span (``tracing.count``): ``jedec_l_bound``,
+    ``jedec_windowed``, ``jedec_pre_held`` and, as their bases,
+    ``jedec_grants`` (granted ACTs, RDs and WRs) and ``jedec_pre_grants``
+    (granted PREs). A ``"table1"`` result has none."""
+    from repro.core.params import CMD_ACT, CMD_PRE, CMD_RD, CMD_WR
+
+    results = [r for r in results if "grants_windowed" in r.counters]
+    if not results:
+        return
+    for name, key in GRANT_SPAN_COUNTERS.items():
+        tracing.count(name, sum(int(r.counters[key]) for r in results))
+    cmds = sum(np.asarray(r.counters["cmd_counts"], np.int64)
+               for r in results)
+    tracing.count("jedec_grants",
+                  int(cmds[CMD_ACT] + cmds[CMD_RD] + cmds[CMD_WR]))
+    tracing.count("jedec_pre_grants", int(cmds[CMD_PRE]))
 
 
 def _batch_results(cfg, num_cycles, trace_list, scheds, qs, rs, ns,

@@ -53,7 +53,12 @@ from repro.core.simulator import (
     _memory_phase,
     _promote_frfcfs,
 )
-from repro.kernels.bank_fsm.fused import NUM_SCAL_OUT, fused_step_pallas
+from repro.kernels.bank_fsm.fused import (
+    NUM_BANK_ROWS_OUT,
+    NUM_JEDEC_SCAL_OUT,
+    NUM_SCAL_OUT,
+    fused_step_pallas,
+)
 from repro.kernels.bank_fsm.ops import default_interpret
 from repro.kernels.bank_fsm.ref import pack_state, unpack_state
 
@@ -93,7 +98,7 @@ def _pre(topo: Topology, sched, trace: Trace, state: SimState, cycle: Array,
         # head PEEK in glue (the split kernel's ABI does the same); the pop
         # bookkeeping runs in-kernel on the qmeta rows
         pop_items, _ = bank_q.peek_valid()
-        bank_rows = jnp.concatenate([
+        rows = [
             packed,
             jnp.stack([
                 bank_q.head, bank_q.count,
@@ -102,7 +107,16 @@ def _pre(topo: Topology, sched, trace: Trace, state: SimState, cycle: Array,
                 state.timing.last_wr[rob],
             ]),
             pop_items.T,
-        ])
+        ]
+        if topo.jedec:
+            # each bank's group registers, then its own gate and bid start
+            gob = jnp.arange(b, dtype=jnp.int32) // topo.banks_per_group
+            tm = state.timing
+            rows.append(jnp.stack([
+                tm.bg_act.reshape(-1)[gob], tm.bg_rd.reshape(-1)[gob],
+                tm.bg_wr.reshape(-1)[gob], state.bank.pre_ready,
+                state.bank.bid_since]))
+        bank_rows = jnp.concatenate(rows)
     bounds, rp_mat = sched.pack()
     # next-arrival distance from nxt, post-admission (what the unfused
     # engine's _next_event reads off the post-edge state)
@@ -144,10 +158,18 @@ def _post(topo: Topology, n: int, state: SimState, cycle: Array, ctx,
     bank_q = BankedFifo(buf=bank_q.buf, head=qmeta2[0], count=qmeta2[1],
                         limit=bank_q.limit)
     sel = timing2[:, ::topo.banks_per_rank]              # [7, R] rank-uniform
+    groups = {}
+    if topo.jedec:
+        jrows = bank2[NUM_BANK_ROWS_OUT:]
+        # [3, R*BG] group-uniform -> each register's [R, BG]
+        gsel = jrows[:3, ::topo.banks_per_group].reshape(
+            3, topo.num_ranks, topo.bankgroups)
+        groups = dict(bg_act=gsel[0], bg_rd=gsel[1], bg_wr=gsel[2])
+        new_bank = new_bank._replace(pre_ready=jrows[3], bid_since=jrows[4])
     timing = TimingState(
         last_act=sel[0],
         act_win=jnp.stack([sel[1], sel[2], sel[3], sel[4]], axis=1),
-        last_rd=sel[5], last_wr=sel[6],
+        last_rd=sel[5], last_wr=sel[6], **groups,
     )
     delta = scal_row[0]
     resp_rr = scal_row[1]
@@ -173,9 +195,14 @@ def _post(topo: Topology, n: int, state: SimState, cycle: Array, ctx,
         t_complete = state.t_complete.at[
             jnp.where(ack_valid, fitem_id, n)
         ].set(cycle, mode="drop")
+    stats = None
+    if topo.jedec:
+        at = NUM_SCAL_OUT + 2 * channels
+        stats = tuple(scal_row[at + k] for k in range(NUM_JEDEC_SCAL_OUT))
     counters = power_lib.update_counters(
         state.counters, issued_cmds, state.bank.st, seg,
-        tier_idx=tier_of_bank(topo) if topo.tiers > 1 else None)
+        tier_idx=tier_of_bank(topo) if topo.tiers > 1 else None,
+        grant_stats=stats)
 
     new_state = SimState(
         next_arrival=next_arrival,
@@ -202,8 +229,9 @@ def _post(topo: Topology, n: int, state: SimState, cycle: Array, ctx,
 def _kernel_layout(ops):
     """Lane-stacked per-lane operands ([L, ...] leading axis, as ``_pre``
     under vmap produces them) -> the kernel ABI of
-    :mod:`repro.kernels.bank_fsm.fused`: bank rows [23, L, B], resp_buf
-    [F, L, Qr], rp_mat [L, T*S*NP], bounds [L, S], scal [L, 8+C]."""
+    :mod:`repro.kernels.bank_fsm.fused`: bank rows [23, L, B] ([28, L, B]
+    under ``"jedec"``), resp_buf [F, L, Qr], rp_mat [L, T*S*NP], bounds
+    [L, S], scal [L, 8+C]."""
     bank_rows, resp_buf, rp_mat, bounds, scal = ops
     lanes = bank_rows.shape[0]
     return (jnp.moveaxis(bank_rows, 0, 1),

@@ -37,9 +37,18 @@ class PowerConfig:
     clock_ghz: float = 1.2
 
 
+#: the ``"jedec"`` timing model's grant counters: ACT/RD/WR grants whose
+#: legal cycle a same-bank-group (L) window set, ACT/RD/WR grants a window
+#: held after their bid began, and PRE grants the precharge gate held
+JEDEC_COUNTERS = ("grants_l_bound", "grants_windowed", "pre_held")
+
+
 def make_counters(num_banks: int, num_segments: int = 1,
-                  num_tiers: int = 1) -> Dict[str, Array]:
+                  num_tiers: int = 1, jedec: bool = False) -> Dict[str, Array]:
+    jedec_counters = {k: jnp.zeros((), jnp.int32) for k in JEDEC_COUNTERS
+                      } if jedec else {}
     return {
+        **jedec_counters,
         "cmd_counts": jnp.zeros((NUM_CMDS,), jnp.int32),
         "sref_cycles": jnp.zeros((), jnp.int32),
         "active_cycles": jnp.zeros((), jnp.int32),   # banks not IDLE/SREF
@@ -85,9 +94,12 @@ def update_counters(
     st: Array,             # int32[B] bank states
     seg: Array = 0,        # scalar int32: active ParamSchedule segment
     tier_idx=None,         # static int32[B] bank->tier map (None: one tier)
+    grant_stats=None,      # "jedec": this edge's JEDEC_COUNTERS increments
 ) -> Dict[str, Array]:
     from repro.core.params import S_IDLE, S_SREF
 
+    jedec = ({k: counters[k] + v for k, v in zip(JEDEC_COUNTERS, grant_stats)}
+             if grant_stats is not None else {})
     one_hot = jnp.zeros((NUM_CMDS,), jnp.int32).at[issued_cmd].add(1)
     # CMD_NOP slot accumulates junk; zero it out at report time.
     sref = (st == S_SREF).sum().astype(jnp.int32)
@@ -95,6 +107,7 @@ def update_counters(
     b = st.shape[0]
     t_sref, t_idle, t_active = _tier_state_counts(counters, st, tier_idx)
     return {
+        **jedec,
         "cmd_counts": counters["cmd_counts"] + one_hot,
         "sref_cycles": counters["sref_cycles"] + sref,
         "idle_cycles": counters["idle_cycles"] + idle,
@@ -139,6 +152,7 @@ def skip_counters(
     delta = jnp.asarray(delta, jnp.int32)
     t_sref, t_idle, t_active = _tier_state_counts(counters, st, tier_idx)
     return {
+        **counters,  # the grant counters: no grant in an inert cycle
         # each skipped cycle issues CMD_NOP on every channel (junk slot,
         # but bit-identical to the per-cycle engine's one_hot accumulation)
         "cmd_counts": counters["cmd_counts"].at[CMD_NOP].add(delta * channels),

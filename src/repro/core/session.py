@@ -44,7 +44,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import tracing
-from repro.core.engine import _PAD_T, _run_window_jit, _sched_i32, _timed
+from repro.core.engine import (
+    _PAD_T,
+    _run_window_jit,
+    _sched_i32,
+    _timed,
+    count_grants,
+)
 from repro.core.params import MemSimConfig
 from repro.core.simulator import SimResult, SimState, Trace, init_state
 
@@ -184,7 +190,8 @@ class SimSession:
         if capacity < 1:
             raise ValueError(f"capacity={capacity} must be >= 1")
         topo = cfg.topology()
-        sched = _sched_i32(cfg.runtime() if params is None else params)
+        sched = _sched_i32(cfg.runtime() if params is None else params,
+                           cfg.timing_model)
         ql = cfg.queue_size if queue_size is None else queue_size
         rl = (cfg.resp_queue_size if resp_queue_size is None
               else resp_queue_size)
@@ -327,7 +334,9 @@ class SimSession:
         to it, per the session exactness contract). Span:
         ``memsim.engine.to_result``."""
         with tracing.span("memsim.engine.to_result"):
-            return self._result()
+            res = self._result()
+            count_grants([res])
+            return res
 
     def _result(self) -> SimResult:
         n = self._n_filled

@@ -63,6 +63,7 @@ from repro.core.engine import (
     _run_window_lanes_jit,
     _sched_i32,
     _timed,
+    count_grants,
 )
 from repro.core.params import MemSimConfig, ParamSchedule, RuntimeParams
 from repro.core.session import WindowReport, _as_arrival_arrays, \
@@ -143,7 +144,8 @@ class SessionBatch:
         if batch_mode not in ("auto", "vmap", "lanes"):
             raise ValueError(f"unknown batch_mode {batch_mode!r}")
         topo = cfg.topology()
-        scheds = [_sched_i32(cfg.runtime() if p is None else p)
+        scheds = [_sched_i32(cfg.runtime() if p is None else p,
+                             cfg.timing_model)
                   for p in _per_lane(params, lanes, "params")]
         sched_stack = ParamSchedule.stack(scheds)
         qls, rls = [], []
@@ -316,7 +318,9 @@ class SessionBatch:
         past that point is inert for them). Span:
         ``memsim.engine.to_result``."""
         with tracing.span("memsim.engine.to_result"):
-            return self._lane_result(lane, num_cycles)
+            res = self._lane_result(lane, num_cycles)
+            count_grants([res])
+            return res
 
     def _lane_result(self, lane: int,
                      num_cycles: Optional[int]) -> SimResult:

@@ -60,6 +60,15 @@ NP = NUM_RUNTIME_PARAMS, C = channels):
            fitem_write, fitem_data, fitem_id, cmd_rr2[C], issued_cmd[C])
            per lane
 
+Under ``timing_model="jedec"`` (and only then) the ABI gains
+NUM_JEDEC_ROWS bank rows at the end of each bank block — in: 23-27, out:
+22-26 = (bg_act, bg_rd, bg_wr gathered per bank from the [R, BG]
+registers, pre_ready, bid_since) — and scal2 gains NUM_JEDEC_SCAL_OUT
+columns after issued_cmd[C]: this edge's (grants_l_bound,
+grants_windowed, pre_held) increments. The kernel then judges ACT/RD/WR
+by the S windows over the rank and the L windows over the bank's group
+and PRE by its bank's gate, both for the grant and for the event bound.
+
 The queue head PEEK (a gather the split path already does in glue) feeds
 the kernel as 4 pop rows instead of shipping the whole queue buffer
 through the ABI; the pop BOOKKEEPING stays in-kernel.
@@ -123,6 +132,24 @@ NUM_BANK_ROWS_IN = 23    # state 10 + qmeta 2 + timing 7 + pop 4
 NUM_BANK_ROWS_OUT = 22   # state 10 + flags 3 + qmeta 2 + timing 7
 NUM_SCAL_IN = 8          # + channels
 NUM_SCAL_OUT = 9         # + 2 * channels
+NUM_JEDEC_ROWS = 5       # bg_act, bg_rd, bg_wr, pre_ready, bid_since
+NUM_JEDEC_SCAL_OUT = 3   # grants_l_bound, grants_windowed, pre_held
+
+
+def bank_rows_in(topo: Topology) -> int:
+    """Input bank rows of ``topo``'s kernel (the jedec rows at the end)."""
+    return NUM_BANK_ROWS_IN + (NUM_JEDEC_ROWS if topo.jedec else 0)
+
+
+def bank_rows_out(topo: Topology) -> int:
+    """Output bank rows of ``topo``'s kernel (the jedec rows at the end)."""
+    return NUM_BANK_ROWS_OUT + (NUM_JEDEC_ROWS if topo.jedec else 0)
+
+
+def scal_out_width(topo: Topology) -> int:
+    """Columns of the per-lane scalar output row."""
+    return (NUM_SCAL_OUT + 2 * topo.channels
+            + (NUM_JEDEC_SCAL_OUT if topo.jedec else 0))
 
 
 def _compute_cmds(st, cur_write):
@@ -151,6 +178,27 @@ def _legal_at(rp, cmd, la, aw0, aw1, aw2, aw3, lr, lw):
     at = jnp.where(cmd == CMD_RD, rd_at, at)
     at = jnp.where(cmd == CMD_WR, wr_at, at)
     return at.astype(jnp.int32)
+
+
+def _jedec_at(rp, cmd, la, aw0, aw1, aw2, aw3, lr, lw, gla, glr, glw,
+              pre_ready):
+    """Lanewise :func:`repro.core.dram_model.jedec_windows`: ``(s_at,
+    l_at)`` on the per-bank expanded rank and bank-group rows."""
+    oldest = jnp.minimum(jnp.minimum(aw0, aw1), jnp.minimum(aw2, aw3))
+    s_at = jnp.full_like(cmd, _NEG)
+    s_at = jnp.where(cmd == CMD_ACT, jnp.maximum(la + rp("tRRDS"),
+                                                 oldest + rp("tFAW")), s_at)
+    s_at = jnp.where(cmd == CMD_RD, jnp.maximum(lr + rp("tCCDS"),
+                                                lw + rp("tWTRS")), s_at)
+    s_at = jnp.where(cmd == CMD_WR, jnp.maximum(lw + rp("tCCDS"),
+                                                lr + rp("tRTW")), s_at)
+    s_at = jnp.where(cmd == CMD_PRE, pre_ready, s_at)
+    l_at = jnp.full_like(cmd, _NEG)
+    l_at = jnp.where(cmd == CMD_ACT, gla + rp("tRRDL"), l_at)
+    l_at = jnp.where(cmd == CMD_RD, jnp.maximum(glr + rp("tCCDL"),
+                                                glw + rp("tWTR")), l_at)
+    l_at = jnp.where(cmd == CMD_WR, glw + rp("tCCDL"), l_at)
+    return s_at.astype(jnp.int32), l_at.astype(jnp.int32)
 
 
 def _resolve_rp_lanes(rp_ref, bnd_ref, cycle, lanes, width,
@@ -243,11 +291,20 @@ def _fused_kernel(topo: Topology, bank_ref, resp_ref, rp_ref, bnd_ref,
     # like the unfused peek — the FSM masks on queue_nonempty)
     pop_rows = tuple(bank_ref[19 + f] for f in range(nf))
     queue_nonempty = qcount > 0
+    jedec = topo.jedec
+    if jedec:
+        gla, glr, glw, pre_ready, bid_since = (
+            bank_ref[NUM_BANK_ROWS_IN + i] for i in range(NUM_JEDEC_ROWS))
 
     # ---- phase 3: bids, legality, per-channel RR grant, record_issue -------
     cmds = _compute_cmds(st, cur_write)
     bids = cmds != CMD_NOP
-    legal = _legal_at(rp, cmds, la, aw0, aw1, aw2, aw3, lr, lw)
+    if jedec:
+        s_at, l_at = _jedec_at(rp, cmds, la, aw0, aw1, aw2, aw3, lr, lw,
+                               gla, glr, glw, pre_ready)
+        legal = jnp.maximum(s_at, l_at)
+    else:
+        legal = _legal_at(rp, cmds, la, aw0, aw1, aw2, aw3, lr, lw)
     eligible = bids & (cycle >= legal)
 
     # per-channel arbitration on the [L, B] block: banks stay on the lane
@@ -310,6 +367,26 @@ def _fused_kernel(topo: Topology, bank_ref, resp_ref, rp_ref, bnd_ref,
     aw3_2 = jnp.where(hit_act & s3, cycle, aw3)
     lr2 = jnp.where(is_rd & upd, cycle, lr)
     lw2 = jnp.where(is_wr & upd, cycle, lw)
+    if jedec:
+        # the same masked update on the winner's bank group (rank-major
+        # group index within the channel), then the granted bank's gate
+        grp_in = wi // topo.banks_per_group
+        upd_g = grp_in == per_channel(chan_reduce(jnp.sum, g_i * grp_in, 0))
+        gla2 = jnp.where(is_act & upd_g, cycle, gla)
+        glr2 = jnp.where(is_rd & upd_g, cycle, glr)
+        glw2 = jnp.where(is_wr & upd_g, cycle, glw)
+        col_gate = cycle + jnp.where(cur_write == 1, rp("tWTP"), rp("tRTP"))
+        pre2 = jnp.where(grant & (st == S_RW_ISSUE),
+                         jnp.maximum(pre_ready, col_gate), pre_ready)
+        pre2 = jnp.where(grant & (st == S_ACT_ISSUE), cycle + rp("tRAS"),
+                         pre2)
+        # the grant counters (repro.core.simulator.grant_stats)
+        held = legal > bid_since
+        windowed = grant & held & ((cmds == CMD_ACT) | (cmds == CMD_RD)
+                                   | (cmds == CMD_WR))
+        stats = [jnp.sum(m.astype(jnp.int32), axis=1, keepdims=True)
+                 for m in (windowed & (l_at > s_at), windowed,
+                           grant & held & (cmds == CMD_PRE))]
 
     # ---- phase 4: response arbitration + respQueue push --------------------
     # resp_buf is field-major [F, L, Qr]: lanes on sublanes, slots on lanes
@@ -356,8 +433,16 @@ def _fused_kernel(topo: Topology, bank_ref, resp_ref, rp_ref, bnd_ref,
     local = _event_bound_combinational(rp2, nxt, st2, timer2, idle2, rdue2)
     cmds_n = _compute_cmds(st2, cur_write2)
     bids_n = cmds_n != CMD_NOP
-    legal_n = _legal_at(rp2, cmds_n, la2, aw0_2, aw1_2, aw2_2, aw3_2, lr2,
-                        lw2)
+    if jedec:
+        legal_n = jnp.maximum(*_jedec_at(rp2, cmds_n, la2, aw0_2, aw1_2,
+                                         aw2_2, aw3_2, lr2, lw2, gla2, glr2,
+                                         glw2, pre2))
+        bid_since2 = jnp.where(
+            ((st2 == S_ACT_ISSUE) | (st2 == S_RW_ISSUE) | (st2 == S_PRE_ISSUE))
+            & (st2 != st), nxt, bid_since)
+    else:
+        legal_n = _legal_at(rp2, cmds_n, la2, aw0_2, aw1_2, aw2_2, aw3_2,
+                            lr2, lw2)
     eligible_n = bids_n & (nxt >= legal_n)
     blocked_n = bids_n & ~eligible_n
     # wait mask must match repro.core.bank_fsm.wait_mask exactly
@@ -385,12 +470,14 @@ def _fused_kernel(topo: Topology, bank_ref, resp_ref, rp_ref, bnd_ref,
             + [want_pop.astype(jnp.int32), rw_done.astype(jnp.int32),
                completed.astype(jnp.int32), qhead2, qcount2,
                la2, aw0_2, aw1_2, aw2_2, aw3_2, lr2, lw2])
+    if jedec:
+        outs += [gla2, glr2, glw2, pre2, bid_since2]
     for i, row in enumerate(outs):
         bank_out_ref[i] = row
     # scalar outputs packed on the lane axis by a select chain (a lane
     # concatenate of [L, 1] columns is a relayout Mosaic refuses)
     cols = ([delta, resp_rr2, resp_head2, resp_count2, ack.astype(jnp.int32)]
-            + fitem + cmd_rr2 + cmd_w_c)
+            + fitem + cmd_rr2 + cmd_w_c + (stats if jedec else []))
     ko = jax.lax.broadcasted_iota(jnp.int32, scal_out_ref.shape, 1)
     acc = jnp.zeros(scal_out_ref.shape, jnp.int32)
     for k, v in enumerate(cols):
@@ -404,19 +491,19 @@ def fused_step_pallas(topo: Topology, bank_rows, resp_buf, rp_mat, bounds,
 
     All shape/ordering contracts are in the module docstring; the lane
     count L is ``bank_rows.shape[1]``. Returns ``(bank_rows2 [22, L, B],
-    resp_buf2 [F, L, Qr], scal2 [L, 9+2C])``."""
+    resp_buf2 [F, L, Qr], scal2 [L, 9+2C])``, with the jedec rows and
+    columns where ``topo.jedec``."""
     _count_invocation()
-    assert bank_rows.shape[0] == NUM_BANK_ROWS_IN
+    assert bank_rows.shape[0] == bank_rows_in(topo)
     assert bank_rows.shape[2] == topo.num_banks, (
         f"bank width {bank_rows.shape[2]} != banks {topo.num_banks}")
     lanes = bank_rows.shape[1]
     kernel = functools.partial(_fused_kernel, topo)
     out_shape = [
-        jax.ShapeDtypeStruct((NUM_BANK_ROWS_OUT,) + bank_rows.shape[1:],
+        jax.ShapeDtypeStruct((bank_rows_out(topo),) + bank_rows.shape[1:],
                              jnp.int32),
         jax.ShapeDtypeStruct(resp_buf.shape, jnp.int32),
-        jax.ShapeDtypeStruct((lanes, NUM_SCAL_OUT + 2 * topo.channels),
-                             jnp.int32),
+        jax.ShapeDtypeStruct((lanes, scal_out_width(topo)), jnp.int32),
     ]
     return pl.pallas_call(kernel, out_shape=out_shape, interpret=interpret,
                           name="fused_fsm")(

@@ -193,3 +193,118 @@ def test_batched_fused_engine_has_no_queue_wide_gather(one_chip, monkeypatch):
     sizes = _gather_sizes(text)
     assert sizes, "no gather found: the HLO text pattern no longer matches"
     assert lanes * topo.num_banks * cfg.queue_size not in sizes, sizes
+
+
+def _while_carry(jaxpr):
+    """Shapes of the engine loop's carried values (the first while loop
+    found, nested jaxprs searched)."""
+    def find(j):
+        for e in j.eqns:
+            if e.primitive.name == "while":
+                return e
+            for v in e.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns") and (w := find(inner)):
+                    return w
+        return None
+
+    loop = find(jaxpr.jaxpr)
+    assert loop is not None
+    return [tuple(v.aval.shape)
+            for v in loop.params["body_jaxpr"].jaxpr.invars]
+
+
+def _kernel_shapes(text):
+    """``(operand shapes, result shapes)`` of the fused kernel's custom
+    call in a compiled TPU HLO text."""
+    line = next(ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln and "custom-call(" in ln)
+    dims = re.compile(r"s32\[([\d,]*)\]")
+
+    def shapes(s):
+        return [tuple(int(d) for d in m.split(",") if d)
+                for m in dims.findall(s)]
+
+    results = line.split("custom-call(")[0]
+    operands = line.split("operand_layout_constraints={")[1].split("}, ")[0]
+    return shapes(operands), shapes(results)
+
+
+def _engine(cfg, lanes):
+    """The jitted engine and its argument shapes: the one-lane skip engine
+    (``lanes`` None) or the lane-batched one."""
+    from repro.core import ParamSchedule, engine
+    from repro.traces import BENCHMARKS
+
+    tr = BENCHMARKS["trace_example"](n=40, gap=5)
+    topo = cfg.topology()
+    if lanes is None:
+        args = (tr, jnp.int32(100_000), engine._sched_i32(cfg.runtime()),
+                jnp.int32(cfg.queue_size), jnp.int32(cfg.resp_queue_size))
+        return functools.partial(engine._run_skip_core, topo), args
+    stacked, _ = engine.stack_traces([tr] * lanes)
+    scheds = ParamSchedule.stack([engine._sched_i32(cfg.runtime())] * lanes)
+    args = (stacked, jnp.int32(100_000), scheds, np.zeros(lanes),
+            np.zeros(lanes))
+    return functools.partial(engine._run_skip_batch_core, topo), args
+
+
+@pytest.mark.parametrize("lanes", [None, 64], ids=["one_lane", "batched"])
+def test_table1_programs_keep_the_kernel_abi(one_chip, monkeypatch, lanes):
+    """The DDR4 (``"table1"``) fused programs at ddr4-2ch's shape carry
+    no bank-group register and keep today's kernel ABI: 23 bank rows in,
+    22 out, 9 + 2C scalar columns out. The loop's only [R, 4]-shaped
+    value is the tFAW window (``act_win``), which a bank-group register
+    of DDR4's 4 groups would share its shape with."""
+    from repro.core import fused_step
+    from repro.kernels.bank_fsm import fused
+
+    monkeypatch.setattr(fused_step, "default_interpret", lambda: False)
+    cfg = MemSimConfig(channels=2, ranks=2, bankgroups=4, banks_per_group=4,
+                       queue_size=128, fsm_backend="fused")
+    assert (fused.NUM_TIMING_ROWS, fused.NUM_BANK_ROWS_IN,
+            fused.NUM_BANK_ROWS_OUT) == (7, 23, 22)
+    fn, args = _engine(cfg, lanes)
+    args = jax.tree_util.tree_map(lambda x: _sds(one_chip, np.shape(x)),
+                                  args)
+    lead = () if lanes is None else (lanes,)
+    carry = _while_carry(jax.make_jaxpr(jax.jit(fn))(*args))
+    assert carry.count(lead + (cfg.num_ranks, cfg.bankgroups)) == 1, carry
+    operands, results = _kernel_shapes(jax.jit(fn).lower(*args).compile()
+                                       .as_text())
+    lanes = lanes or 1
+    b = cfg.num_banks
+    assert operands[0] == (23, lanes, b)
+    assert results[0] == (22, lanes, b)
+    assert results[2] == (lanes, 9 + 2 * cfg.channels)
+
+
+def test_jedec_batched_engine_has_no_queue_wide_gather(one_chip,
+                                                       monkeypatch):
+    """The lane-batched fused engine at the DDR5-4800 sweep's shape (64
+    lanes, 128 banks, queues of 128) under ``"jedec"``: Mosaic takes the
+    kernel with its bank-group and precharge-gate rows (28 in, 27 out),
+    the loop carries the three [R, BG] registers beside the tFAW window,
+    and no gather reads every slot of every bank queue of every lane."""
+    from repro.core import fused_step
+
+    monkeypatch.setattr(fused_step, "default_interpret", lambda: False)
+    cfg = MemSimConfig(channels=2, ranks=2, bankgroups=8, banks_per_group=4,
+                       queue_size=128, fsm_backend="fused",
+                       timing_model="jedec", tRRDS=8, tRRDL=12, tFAW=32,
+                       tCCDS=8, tCCDL=12, tWTRS=52, tWTR=70)
+    lanes = 64
+    fn, args = _engine(cfg, lanes)
+    args = jax.tree_util.tree_map(lambda x: _sds(one_chip, np.shape(x)),
+                                  args)
+    carry = _while_carry(jax.make_jaxpr(jax.jit(fn))(*args))
+    assert carry.count((lanes, cfg.num_ranks, cfg.bankgroups)) == 3, carry
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    operands, results = _kernel_shapes(text)
+    b = cfg.num_banks
+    assert operands[0] == (28, lanes, b)
+    assert results[0] == (27, lanes, b)
+    assert results[2] == (lanes, 9 + 2 * cfg.channels + 3)
+    sizes = _gather_sizes(text)
+    assert sizes, "no gather found: the HLO text pattern no longer matches"
+    assert lanes * b * cfg.queue_size not in sizes, sizes
